@@ -101,9 +101,10 @@ def bench_decode(results: dict, cfg_b, params, shapes: dict, reps: int) -> None:
             eng = _engine(cfg_b, ctx + n_new, use_sketch, params)
             cache = eng.new_cache(B)
             tok = jnp.zeros((B,), jnp.int32)
+            # _decode donates its cache: each call gets a fresh one
             t = timeit(
-                lambda e=eng, c=cache, k=tok, p=ctx: e._decode(
-                    e.params, c, k, jnp.int32(p), n_steps=n_new
+                lambda e=eng, k=tok, p=ctx: e._decode(
+                    e.params, e.new_cache(B), k, jnp.int32(p), n_steps=n_new
                 )[0],
                 reps=reps, warmup=1,
             )

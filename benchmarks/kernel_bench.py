@@ -1,7 +1,7 @@
 """Microbenchmarks for the accum_apply kernel family.
 
-Times the seed scalar-gather Pallas kernel against the vectorized gather→GEMM
-rewrite, the fused (K S, SᵀK S) single-sweep kernel against the two-pass
+Times the vectorized gather→GEMM K·S kernel, the fused (K S, SᵀK S)
+single-sweep kernel against the two-pass
 composition, the structural-vs-dense sketch application (the paper's O(nmd)
 claim), and the progressive engine's O(n·d) incremental step against the
 from-scratch recompute — then writes the results to ``BENCH_kernels.json`` at
@@ -24,7 +24,6 @@ from benchmarks.common import emit, timeit
 from repro.core import apply as A
 from repro.core.apply import sketch_right
 from repro.core.sketch import make_accum_sketch
-from repro.kernels.accum_apply.kernel import accum_apply, accum_apply_scalar
 from repro.kernels.accum_apply.ops import (
     autotune_blocks,
     sketch_both_kernel,
@@ -50,28 +49,17 @@ def bench_config() -> tuple[dict, int]:
 
 
 def bench_accum_apply(results: dict, anchor: dict, reps: int) -> None:
-    """Seed scalar-loop kernel vs vectorized gather→GEMM at the anchor shape."""
+    """Vectorized gather→GEMM K·S at the anchor shape."""
     key = jax.random.PRNGKey(0)
     R, N, d, m = anchor["R"], anchor["N"], anchor["d"], anchor["m"]
     K = jax.random.normal(key, (R, N))
     sk = make_accum_sketch(key, N, d, m)
-    coef = sk.coef.astype(jnp.float32)
     bm, bd = autotune_blocks(R, N, d, m, jnp.float32)
 
-    t_new = timeit(
-        lambda: accum_apply(K, sk.indices, coef, bm=bm, bd=bd, interpret=True),
-        reps=reps)
-    # seed defaults: bm=256, bd=8, scalar per-column gather loop
-    t_old = timeit(
-        lambda: accum_apply_scalar(K, sk.indices, coef, bm=256, bd=8,
-                                   interpret=True), reps=min(reps, 2))
-    speedup = t_old / max(t_new, 1e-9)
+    t_new = timeit(lambda: sketch_right_kernel(K, sk, bm=bm, bd=bd), reps=reps)
     tag = f"R{R}_N{N}_d{d}_m{m}_f32"
-    emit(f"accum_apply_gemm_{tag}", t_new * 1e6, f"scalar/gemm={speedup:.1f}x")
-    emit(f"accum_apply_scalar_{tag}", t_old * 1e6, "seed baseline")
-    results[f"accum_apply_gemm_{tag}"] = {
-        "us": t_new * 1e6, "speedup_vs_scalar": speedup, "blocks": [bm, bd]}
-    results[f"accum_apply_scalar_{tag}"] = {"us": t_old * 1e6}
+    emit(f"accum_apply_gemm_{tag}", t_new * 1e6, "")
+    results[f"accum_apply_gemm_{tag}"] = {"us": t_new * 1e6, "blocks": [bm, bd]}
 
 
 def bench_fused_both(results: dict, anchor: dict, reps: int) -> None:

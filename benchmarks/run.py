@@ -39,6 +39,7 @@ from benchmarks import falkon_bench, fig1_toy
 from benchmarks import fig2_approx_error, fig3_tradeoff, grow_bench
 from benchmarks import kernel_bench, matfree_bench, resilience_bench
 from benchmarks import roofline, schemes_bench, train_bench
+from repro.util import use_compile_cache
 
 SUITES = {
     "fig1": fig1_toy.main,          # paper Fig. 1 (toy tradeoff)
@@ -65,6 +66,7 @@ def main() -> None:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
         argv = [a for a in argv if a != "--smoke"]
     picks = argv or list(SUITES)
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name in picks:

@@ -11,18 +11,19 @@ Static analysis over *traced programs* (jaxprs) rather than runs:
     `fold_in` in `src/repro` must belong to;
   * `repro.analysis.contracts` — per-entry-point budget manifest
     (`contracts.toml`) and its evaluator;
-  * `repro.analysis.hardware` — the overridable `HardwareModel` shared with
-    the roofline extractor in `repro.launch.analysis`.
+  * `repro.analysis.hardware` — the per-`device_kind` peak table
+    (`HardwareModel` rows) shared with the roofline extractor in
+    `repro.launch.analysis`.
 
 Gate: ``python -m repro.analysis check`` (``--update`` ratchets measured
 peaks downward, like the coverage gate).
 """
 from repro.analysis.hardware import (  # noqa: F401
-    DEFAULT_HARDWARE,
+    PEAKS,
     TPU_V5E,
     HardwareModel,
     get_default_hardware,
-    set_default_hardware,
+    hardware_for,
 )
 from repro.analysis.rng import (  # noqa: F401
     RngIssue,
@@ -44,11 +45,11 @@ from repro.analysis.trace import (  # noqa: F401
 )
 
 __all__ = [
-    "DEFAULT_HARDWARE",
+    "PEAKS",
     "TPU_V5E",
     "HardwareModel",
     "get_default_hardware",
-    "set_default_hardware",
+    "hardware_for",
     "RngIssue",
     "RngReport",
     "check_fold_in_sites",

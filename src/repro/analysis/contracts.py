@@ -29,6 +29,7 @@ from __future__ import annotations
 import ast
 import dataclasses
 import pathlib
+import tomllib
 
 import jax
 import jax.numpy as jnp
@@ -59,48 +60,12 @@ def eval_budget(expr: str, probe: dict) -> int:
 
 
 # --------------------------------------------------------------------------- #
-# manifest io — honest TOML via tomllib where available, with a fallback
-# parser for the flat subset this file uses (py3.10 without tomli)
+# manifest io — tomllib reads, ``dump_manifest`` writes the flat subset
 # --------------------------------------------------------------------------- #
-
-def _parse_value(raw: str):
-    raw = raw.strip()
-    if raw == "true":
-        return True
-    if raw == "false":
-        return False
-    try:
-        return ast.literal_eval(raw)
-    except (ValueError, SyntaxError):
-        return raw.strip('"')
-
-
-def _parse_toml_flat(text: str) -> dict:
-    out: dict = {}
-    cur = None
-    for line in text.splitlines():
-        s = "" if line.strip().startswith("#") else line.split("#", 1)[0].strip()
-        if not s:
-            continue
-        if s.startswith("[") and s.endswith("]"):
-            cur = s[1:-1].strip().strip('"')
-            out[cur] = {}
-            continue
-        if "=" in s and cur is not None:
-            k, v = s.split("=", 1)
-            out[cur][k.strip()] = _parse_value(v)
-    return out
-
 
 def load_manifest(path: pathlib.Path | str = CONTRACTS_PATH) -> dict:
     """Read contracts.toml into {name: {key: value}}."""
-    text = pathlib.Path(path).read_text()
-    try:
-        import tomllib
-
-        return tomllib.loads(text)
-    except ImportError:
-        return _parse_toml_flat(text)
+    return tomllib.loads(pathlib.Path(path).read_text())
 
 
 def _emit_value(v) -> str:

@@ -1,13 +1,9 @@
 """One hardware model shared by the roofline and the trace-contract analyzer.
 
-`launch/analysis.py` used to hardcode TPU v5e peak numbers at module scope, so
-roofline terms and any other consumer of chip constants drifted independently.
-This dataclass is the single source of truth: the roofline divides by its
-bandwidths, and `repro.analysis` contracts can express budgets relative to the
-same chip (e.g. "this entry point must stay under one HBM's worth of
-intermediates").  Override per call site (`HardwareModel(peak_flops=...)`) or
-swap the default with `set_default_hardware` — module-scope constants are
-gone.
+Peak numbers live in one table keyed by JAX's ``device_kind``, each row with
+its source.  Consumers take a :class:`HardwareModel` explicitly, or ask
+:func:`get_default_hardware` for the chip the process runs on — a device
+whose kind is not in the table is an error, never a silent default.
 """
 from __future__ import annotations
 
@@ -16,35 +12,40 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class HardwareModel:
-    """Per-chip peak numbers used for roofline terms and trace budgets.
+    """Per-chip peak numbers used for roofline terms and trace budgets."""
 
-    Defaults describe a TPU v5e-class chip: bf16 matmul peak, HBM bandwidth,
-    and per-link ICI bandwidth.  All consumers take an instance (defaulting to
-    `DEFAULT_HARDWARE`) instead of reading module constants, so a v5p/v6e/GPU
-    profile is one constructor call away.
-    """
-
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12      # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9           # B/s per chip
-    ici_bw: float = 50e9            # B/s per link
-    hbm_bytes: float = 16e9         # HBM capacity per chip
-    vmem_bytes: float = 128e6       # on-chip vector memory
+    name: str
+    peak_flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float                   # B/s per chip
+    ici_bw: float                   # B/s per link
+    hbm_bytes: float                # HBM capacity per chip
+    vmem_bytes: float               # on-chip vector memory
 
 
-TPU_V5E = HardwareModel()
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 16 GB HBM, 1,600 Gbit/s ICI per chip (four links of 50 GB/s).
+TPU_V5E = HardwareModel(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                        ici_bw=50e9, hbm_bytes=16e9, vmem_bytes=128e6)
 
-DEFAULT_HARDWARE = TPU_V5E
+# keyed by ``jax.devices()[0].device_kind``
+PEAKS: dict[str, HardwareModel] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareModel:
+    """The peak table's row for ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak numbers for device kind {device_kind!r}; add a sourced "
+            f"row to repro.analysis.hardware.PEAKS (known: {sorted(PEAKS)})"
+        ) from None
 
 
 def get_default_hardware() -> HardwareModel:
-    """The process-wide default chip profile (used when no override is passed)."""
-    return DEFAULT_HARDWARE
+    """The peak numbers of the chip this process runs on."""
+    import jax
 
-
-def set_default_hardware(hw: HardwareModel) -> HardwareModel:
-    """Swap the process-wide default chip profile; returns the previous one."""
-    global DEFAULT_HARDWARE
-    prev = DEFAULT_HARDWARE
-    DEFAULT_HARDWARE = hw
-    return prev
+    return hardware_for(jax.devices()[0].device_kind)

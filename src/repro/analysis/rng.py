@@ -36,10 +36,7 @@ import numpy as np
 
 from repro.analysis import streams as streams_mod
 
-try:
-    from jax.extend.core import Literal as _JaxLiteral
-except ImportError:                                    # older jax
-    from jax.core import Literal as _JaxLiteral
+from jax.extend.core import Literal as _JaxLiteral
 _LITERAL_TYPES = (_JaxLiteral,)
 
 # --------------------------------------------------------------------------- #

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.analysis.streams import HOLDOUT_STREAM as _HOLDOUT_STREAM
 from repro.analysis.streams import REFINE_STREAM as _REFINE_STREAM
+from repro.core.kernels_math import f32_einsum, f32_matmul
 from repro.core.sketch import AccumSketch, AccumState, make_accum_sketch
 from repro.util import env_flag
 
@@ -61,14 +62,14 @@ def sketch_right(K: jax.Array, sk: AccumSketch) -> jax.Array:
     """K S for K of shape (r, n) → (r, d). O(r·m·d)."""
     cols = jnp.take(K, sk.indices.reshape(-1), axis=1)          # (r, m*d)
     cols = cols.reshape(K.shape[0], sk.m, sk.d)
-    return jnp.einsum("rmd,md->rd", cols, sk.coef)
+    return f32_einsum("rmd,md->rd", cols, sk.coef)
 
 
 def sketch_left(sk: AccumSketch, M: jax.Array) -> jax.Array:
     """Sᵀ M for M of shape (n, c) → (d, c). O(m·d·c)."""
     rows = jnp.take(M, sk.indices.reshape(-1), axis=0)           # (m*d, c)
     rows = rows.reshape(sk.m, sk.d, M.shape[-1])
-    return jnp.einsum("mdc,md->dc", rows, sk.coef)
+    return f32_einsum("mdc,md->dc", rows, sk.coef)
 
 
 def sketch_vec(sk: AccumSketch, v: jax.Array) -> jax.Array:
@@ -267,7 +268,7 @@ def block_left(idx_blk: jax.Array, coef_blk: jax.Array, M: jax.Array) -> jax.Arr
     same G the C update produced; no second pass over anything n-sized)."""
     B, d = idx_blk.shape
     rows = jnp.take(M, idx_blk.reshape(-1), axis=0).reshape(B, d, M.shape[-1])
-    return jnp.einsum("bdc,bd->dc", rows.astype(jnp.float32), coef_blk)
+    return f32_einsum("bdc,bd->dc", rows.astype(jnp.float32), coef_blk)
 
 
 def batch_w_update(state: AccumState, TtC: jax.Array, TtG: jax.Array,
@@ -412,7 +413,7 @@ def _accum_grow_batched_impl(K, state: AccumState, B: int,
     else:
         n = K.shape[0]
         cols = jnp.take(K, idx_blk.reshape(-1), axis=1).astype(jnp.float32)
-        G = jnp.einsum("nbd,bd->nd", cols.reshape(n, B, state.d), coef_blk)
+        G = f32_einsum("nbd,bd->nd", cols.reshape(n, B, state.d), coef_blk)
         C_new = a * state.C + G
         TtG = block_left(idx_blk, coef_blk, G)
         TtC = block_left(idx_blk, coef_blk, state.C)
@@ -500,7 +501,7 @@ def make_holdout_estimator(key: jax.Array, K: jax.Array, num: int = 64,
 
     def estimate(state: AccumState) -> jax.Array:
         Ch = jnp.take(state.C, hold, axis=0)
-        Khat = Ch @ _psd_apply_pinv(state.W, Ch.T, jitter)
+        Khat = f32_matmul(Ch, _psd_apply_pinv(state.W, Ch.T, jitter))
         est = jnp.linalg.norm(Kh - Khat) / denom
         return jnp.where(jnp.isfinite(est), est, jnp.inf).astype(jnp.float32)
 
@@ -527,13 +528,14 @@ def make_hutchinson_estimator(key: jax.Array, K: jax.Array, num_probes: int = 8,
     if op is not None:
         KZ = op.matvec(Z)                              # streamed, O(chunk·n) mem
     else:
-        KZ = K.astype(jnp.float32) @ Z                 # one-time O(n²·q)
+        KZ = f32_matmul(K.astype(jnp.float32), Z)     # one-time O(n²·q)
     zKz = jnp.einsum("nq,nq->q", Z, KZ)
     denom = jnp.maximum(jnp.mean(zKz), 1e-30)
 
     def estimate(state: AccumState) -> jax.Array:
-        CtZ = state.C.T @ Z                            # (d, q) — O(n·d·q)
-        zKhatz = jnp.einsum("dq,dq->q", CtZ, _psd_apply_pinv(state.W, CtZ, jitter))
+        CtZ = f32_matmul(state.C.T, Z)  # (d, q) — O(n·d·q)
+        zKhatz = f32_einsum("dq,dq->q", CtZ,
+                            _psd_apply_pinv(state.W, CtZ, jitter))
         est = jnp.maximum(jnp.mean(zKz - zKhatz), 0.0) / denom
         return jnp.where(jnp.isfinite(est), est, jnp.inf).astype(jnp.float32)
 

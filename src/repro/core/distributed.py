@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -53,20 +52,13 @@ from repro.core.kernel_op import (
     stream_cols,
     stream_cols_slabs,
 )
+from repro.core.kernels_math import f32_einsum, f32_gram, f32_matmul
 from repro.core.sketch import AccumSketch, AccumState
 
 DATA_AXIS = "data"
 
 
-def _shard_map():
-    """Version-shimmed shard_map (jax 0.4.x ships it in experimental, newer
-    jax at the top level; check_rep was renamed check_vma)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    chk = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-           else "check_rep")
-    return functools.partial(sm, **{chk: False})
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 # --------------------------------------------------------------------------- #
@@ -164,7 +156,7 @@ def sharded_take_rows(M: jax.Array, idx: jax.Array, mesh: Mesh) -> jax.Array:
         r = jnp.take(mb, local, axis=0) * inside[:, None].astype(mb.dtype)
         return jax.lax.psum(r, DATA_AXIS)
 
-    return _shard_map()(
+    return _shard_map(
         body, mesh=mesh, in_specs=(P(DATA_AXIS, None), P(None)),
         out_specs=P(None, None))(Mp, idx)
 
@@ -179,13 +171,10 @@ def sharded_gram(Am: jax.Array, Bm: jax.Array, mesh: Mesh) -> jax.Array:
     Ap, Bp = _pad_to(Am, total), _pad_to(Bm, total)
 
     def body(ab, bb):
-        part = jax.lax.dot_general(
-            ab.astype(jnp.float32), bb.astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return jax.lax.psum(part, DATA_AXIS)
+        hi, lo = f32_gram(ab, bb, parts=True)
+        return jax.lax.psum(hi, DATA_AXIS) + jax.lax.psum(lo, DATA_AXIS)
 
-    return _shard_map()(
+    return _shard_map(
         body, mesh=mesh, in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None)),
         out_specs=P(None, None))(Ap, Bp)
 
@@ -196,8 +185,7 @@ def sharded_sketch_left(sk: AccumSketch, M: jax.Array, mesh: Mesh) -> jax.Array:
     combination coefficients."""
     rows = sharded_take_rows(M, sk.indices.reshape(-1), mesh)       # (m·d, c)
     rows = rows.reshape(sk.m, sk.d, M.shape[-1])
-    return jnp.einsum("mdc,md->dc", rows,
-                      sk.coef.astype(rows.dtype))
+    return f32_einsum("mdc,md->dc", rows, sk.coef.astype(rows.dtype))
 
 
 # --------------------------------------------------------------------------- #
@@ -254,7 +242,7 @@ def sharded_weighted_cols(
     def body(xb, lm_, cf):
         return tile(xb, lm_, cf)
 
-    C = _shard_map()(
+    C = _shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(None, None), P(None, None)),
         out_specs=P(DATA_AXIS, None))(_pad_to(Xq, rows * D), lm, coef)
@@ -264,10 +252,17 @@ def sharded_weighted_cols(
 def sharded_sketch_both(
     op: KernelOperator, sk: AccumSketch, mesh: Mesh, *,
     chunk: int | None = None, use_kernel: bool | None = None,
+    padded: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """(C, W) = (K S, SᵀK S) in ONE mapped launch: each device computes its
     C tile locally, gathers the landmark rows it owns, and W arrives as a
-    psum of the per-shard SᵀC partials — no second pass over C."""
+    psum of the per-shard SᵀC partials — no second pass over C.
+
+    ``padded`` returns C with its ⌈n/D⌉·D rows (the padded rows exact zeros),
+    row-sharded: one (⌈n/D⌉, d) tile per device.  The public (n, d) C is
+    replicated on every device when D does not divide n (JAX shards a
+    dimension only evenly), so callers that reduce C further keep it
+    padded."""
     mesh = resolve_mesh(mesh)
     D = _data_size(mesh)
     if use_kernel is None:
@@ -291,16 +286,16 @@ def sharded_sketch_both(
         inside = (idx_flat >= lo) & (idx_flat < lo + rows)
         local = jnp.where(inside, idx_flat - lo, 0)
         crows = jnp.take(cb, local, axis=0) * inside[:, None].astype(cb.dtype)
-        Wp = jnp.einsum("mdc,md->dc", crows.reshape(m, d, d),
+        Wp = f32_einsum("mdc,md->dc", crows.reshape(m, d, d),
                         cf.astype(crows.dtype))
         return cb, jax.lax.psum(Wp, DATA_AXIS)
 
-    C, W = _shard_map()(
+    C, W = _shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(None, None), P(None, None), P(None)),
         out_specs=(P(DATA_AXIS, None), P(None, None)))(
             _pad_to(op.X, rows * D), lm, coef, sk.indices.reshape(-1))
-    return (C[:n] if rows * D != n else C), W
+    return (C[:n] if rows * D != n and not padded else C), W
 
 
 def sharded_matvec(
@@ -322,14 +317,14 @@ def sharded_matvec(
 
     def body(xb, Xall, Zall):
         def blk(xc):
-            return kf(xc, Xall).astype(jnp.float32) @ Zall
+            return f32_matmul(kf(xc, Xall).astype(jnp.float32), Zall)
 
         out = _scan_row_chunks(xb, min(chunk, xb.shape[0]), blk)
         lo = jax.lax.axis_index(DATA_AXIS) * rows
         live = (lo + jnp.arange(rows)) < n
         return jnp.where(live[:, None], out, 0.0)
 
-    out = _shard_map()(
+    out = _shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(None, None), P(None, None)),
         out_specs=P(DATA_AXIS, None))(Xp, Xp, Zp)
@@ -384,7 +379,7 @@ def _sharded_step(opp: KernelOperator, state: AccumState, mesh: Mesh,
         crows = jnp.take(cb, local, axis=0) * inside[:, None].astype(cb.dtype)
         return c_new, jax.lax.psum(crows, DATA_AXIS)
 
-    C_new, Crows = _shard_map()(
+    C_new, Crows = _shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), P(None, None),
                   P(None), P(None), P()),
@@ -426,15 +421,15 @@ def _sharded_batched(opp: KernelOperator, state: AccumState, B: int,
         return (c_new, jax.lax.psum(grows, DATA_AXIS),
                 jax.lax.psum(crows, DATA_AXIS))
 
-    C_new, Grows, Crows = _shard_map()(
+    C_new, Grows, Crows = _shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), P(None, None),
                   P(None, None), P(None), P()),
         out_specs=(P(DATA_AXIS, None), P(None, None), P(None, None)))(
             opp.X, state.C, lm, coef_blk, idx_blk.reshape(-1), a)
 
-    TtG = jnp.einsum("bdc,bd->dc", Grows.reshape(B, d, d), coef_blk)
-    TtC = jnp.einsum("bdc,bd->dc", Crows.reshape(B, d, d), coef_blk)
+    TtG = f32_einsum("bdc,bd->dc", Grows.reshape(B, d, d), coef_blk)
+    TtC = f32_einsum("bdc,bd->dc", Crows.reshape(B, d, d), coef_blk)
     W_new = A.batch_w_update(state, TtC, TtG, a)
     return dataclasses.replace(state, C=C_new, W=W_new, m=state.m + B)
 
@@ -556,7 +551,7 @@ def make_sharded_holdout_estimator(key: jax.Array, K, mesh, num: int = 64,
 
     def estimate(state: AccumState) -> jax.Array:
         Ch = sharded_take_rows(state.C, hold, mesh)
-        Khat = Ch @ A._psd_apply_pinv(state.W, Ch.T, jitter)
+        Khat = f32_matmul(Ch, A._psd_apply_pinv(state.W, Ch.T, jitter))
         est = jnp.linalg.norm(Kh - Khat) / denom
         return jnp.where(jnp.isfinite(est), est, jnp.inf).astype(jnp.float32)
 
@@ -580,7 +575,7 @@ def make_sharded_hutchinson_estimator(key: jax.Array, K, mesh,
     def estimate(state: AccumState) -> jax.Array:
         Zp = _pad_to(Z, state.C.shape[0])       # engine states carry padded C
         CtZ = sharded_gram(state.C, Zp, mesh)
-        zKhatz = jnp.einsum("dq,dq->q", CtZ,
+        zKhatz = f32_einsum("dq,dq->q", CtZ,
                             A._psd_apply_pinv(state.W, CtZ, jitter))
         est = jnp.maximum(jnp.mean(zKz - zKhatz), 0.0) / denom
         return jnp.where(jnp.isfinite(est), est, jnp.inf).astype(jnp.float32)

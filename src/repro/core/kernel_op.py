@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import apply as A
-from repro.core.kernels_math import get_kernel
+from repro.core.kernels_math import f32_einsum, f32_matmul, get_kernel
 from repro.core.sketch import AccumSketch
 
 # dense() materializes the n×n kernel — refuse above this n unless forced
@@ -79,7 +79,7 @@ def stream_cols(
 
     def _block(xb):
         slab = kernel_fn(xb, landmarks).astype(acc_t)           # (b, m·d)
-        return jnp.einsum("bmd,md->bd", slab.reshape(xb.shape[0], m, d), coef_a)
+        return f32_einsum("bmd,md->bd", slab.reshape(xb.shape[0], m, d), coef_a)
 
     return _scan_row_chunks(Xq, chunk, _block)
 
@@ -293,7 +293,7 @@ class KernelOperator:
         Z32 = Zm.astype(jnp.float32)
 
         def _block(xb):
-            return kf(xb, self.X).astype(jnp.float32) @ Z32
+            return f32_matmul(kf(xb, self.X).astype(jnp.float32), Z32)
 
         out = _scan_row_chunks(self.X, chunk, _block)
         return out[:, 0] if Z.ndim == 1 else out

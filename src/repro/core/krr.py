@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import apply as A
+from repro.core.kernels_math import f32_gram, f32_matmul
 from repro.core.sketch import AccumSketch
 
 
@@ -107,7 +108,7 @@ class SketchedKRR:
         kernel evaluations, never an n_test × n matrix.  ``mesh`` shards the
         test rows (operator-fitted models only)."""
         if self.op is not None and self.sk is not None:
-            return self.op.cross_cols(X_test, self.sk, mesh=mesh) @ self.theta
+            return f32_matmul(self.op.cross_cols(X_test, self.sk, mesh=mesh), self.theta)
         if mesh is not None:
             # every other mesh entry point raises for non-operator inputs;
             # silently running single-device here would be a lie
@@ -124,8 +125,8 @@ class SketchedKRR:
             C_test = stream_cols(X_test, lm, self.sk.coef, self.kernel_fn)
         else:
             K_test = self.kernel_fn(X_test, self.X_train)
-            C_test = K_test @ self.S_dense
-        return C_test @ self.theta
+            C_test = f32_matmul(K_test, self.S_dense)
+        return f32_matmul(C_test, self.theta)
 
 
 def _fit_from_C(C: jax.Array, W: jax.Array, y: jax.Array, lam: float,
@@ -133,23 +134,25 @@ def _fit_from_C(C: jax.Array, W: jax.Array, y: jax.Array, lam: float,
     """Given C = K S (n,d) and W = SᵀKS (d,d), solve the Woodbury system.
 
     With ``mesh`` (row-sharded C) the two n-contractions reduce via psum —
-    the d×d solve and the row-wise fitted values need no communication.
+    the d×d solve and the row-wise fitted values need no communication.  C
+    may carry zero rows past the n of y (a padded sharded C); the fitted
+    values are those of y's rows.
     Returns (theta, fitted, solve-health) — the health dict carries the solve
     ladder's traced scalars and is threaded into ``SketchedKRR.info``."""
-    n = C.shape[0]
+    n = y.shape[0]
     if mesh is not None:
         from repro.core import distributed as D
 
         CtC = D.sharded_gram(C, C, mesh)
-        rhs = D.sharded_gram(C, y[:, None], mesh)[:, 0]
+        rhs = D.sharded_gram(C, D._pad_to(y[:, None], C.shape[0]), mesh)[:, 0]
     else:
-        CtC = C.T @ C
-        rhs = C.T @ y                          # SᵀK Y  (K symmetric)
+        CtC = f32_gram(C, C)
+        rhs = f32_gram(C, y[:, None])[:, 0]           # SᵀK Y  (K symmetric)
     from repro.resilience.degrade import solve_psd_ladder
 
     M = CtC + n * lam * W                      # SᵀK²S + nλ SᵀKS
     theta, health = solve_psd_ladder(M, rhs.astype(M.dtype))
-    return theta, C @ theta, health
+    return theta, f32_matmul(C, theta)[:n], health
 
 
 def krr_sketched_fit(
@@ -171,7 +174,15 @@ def krr_sketched_fit(
     across shards — only d-vectors and d×d blocks cross devices, so the
     Woodbury solve and predict are unchanged."""
     op = A._operator(K)
-    C, W = A.sketch_both(K, sk, use_kernel=use_kernel, mesh=mesh)
+    if mesh is not None:
+        from repro.core import distributed as D
+
+        # C stays padded: its (n, d) slice would sit whole on every device
+        C, W = D.sharded_sketch_both(D._operator_required(K), sk,
+                                     D.resolve_mesh(mesh),
+                                     use_kernel=use_kernel, padded=True)
+    else:
+        C, W = A.sketch_both(K, sk, use_kernel=use_kernel)
     theta, fitted, health = _fit_from_C(C, W, y, lam, mesh=mesh)
     if op is not None:
         return SketchedKRR(theta, sk, None, op.X, op.kernel_fn, fitted,
@@ -184,8 +195,8 @@ def krr_sketched_fit_dense(
     X_train: jax.Array | None = None, kernel_fn: Callable | None = None,
 ) -> SketchedKRR:
     """Dense-sketch baseline path (Gaussian sketching, sparse RP): O(n²d)."""
-    C = K @ S
-    W = S.T @ C
+    C = f32_matmul(K, S)
+    W = f32_matmul(S.T, C)
     theta, fitted, health = _fit_from_C(C, W, y, lam)
     return SketchedKRR(theta, None, S, X_train, kernel_fn, fitted, info=health)
 
@@ -252,13 +263,13 @@ def _pcg_solve(C: jax.Array, W: jax.Array, y: jax.Array, lam: float,
             return D.sharded_gram(C, v[:, None], mesh)[:, 0]
     else:
         def _ct(v):
-            return C.T @ v
+            return f32_matmul(C.T, v)
     jitter = 1e-8 * (jnp.trace(W) / d + 1e-30)
     L, lower = jax.scipy.linalg.cho_factor(
         W + jitter * jnp.eye(d, dtype=W.dtype), lower=True)
 
     def matvec(t):
-        return _ct(C @ t) + n * lam * (W @ t)
+        return _ct(f32_matmul(C, t)) + n * lam * f32_matmul(W, t)
 
     def precond(r):
         # (nλ W)⁻¹ ≈ the dominant small-eigenvalue part of the operator
@@ -309,7 +320,7 @@ def krr_sketched_fit_pcg(
         W = _sketch_left_routed(sk, C, use_kernel)
     W = 0.5 * (W + W.T)
     theta = _pcg_solve(C, W, y, lam, iters, mesh=mesh)
-    return SketchedKRR(theta, sk, None, X, kernel_fn, C @ theta, op=op)
+    return SketchedKRR(theta, sk, None, X, kernel_fn, f32_matmul(C, theta), op=op)
 
 
 # --------------------------------------------------------------------------- #
@@ -382,9 +393,10 @@ def krr_sketched_fit_pcg_adaptive(
         schedule=schedule, scheme=scheme, scheme_lam=scheme_lam)
     theta = _pcg_solve(C, W, y, lam, iters, mesh=mesh)
     if op is not None:
-        return SketchedKRR(theta, sk, None, op.X, op.kernel_fn, C @ theta,
+        return SketchedKRR(theta, sk, None, op.X, op.kernel_fn, f32_matmul(C, theta),
                            info=info, op=op)
-    return SketchedKRR(theta, sk, None, X_train, kernel_fn, C @ theta, info=info)
+    return SketchedKRR(theta, sk, None, X_train, kernel_fn, f32_matmul(C, theta),
+                       info=info)
 
 
 def insample_error(f_a: jax.Array, f_b: jax.Array) -> jax.Array:

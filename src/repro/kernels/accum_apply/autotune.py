@@ -1,21 +1,24 @@
 """Measured autotune cache for the accum_apply kernel family.
 
-PR 1's block sizes came from a hand-maintained static table keyed on exact
-shapes, with a VMEM-budget heuristic for everything else — fine for the
-benchmark anchors, wrong for any shape nobody measured.  This module replaces
-that with a MEASURED cache:
+Block sizes come from a MEASURED cache, with a fixed heuristic in each entry
+point (``ops.py``) as the fallback:
 
   * the first eligible call at a (kernel, shape, dtype, backend) key times the
     candidate tilings once on the caller's real arrays and keeps the winner;
   * winners persist to a JSON cache (``REPRO_AUTOTUNE_CACHE``, default
     ``~/.cache/repro/autotune.json``) so later processes skip the measurement;
-  * a corrupt, missing, or unwritable cache degrades silently to the static
-    table / heuristic — autotuning must never be able to break a run.
+  * a corrupt, missing, or unwritable cache degrades to the heuristic (a
+    corrupt file is recorded in the global ``HealthReport``);
+  * a candidate tiling is skipped only when the compiler refuses it for
+    memory (VMEM / RESOURCE_EXHAUSTED); the skip is kept in ``refusals()``.
+    Any other failure is a bug in the kernel or its wrapper and propagates,
+    and a measurement in which every candidate is refused raises — a
+    failing kernel never hides behind the fallback tiling.
 
 Measurement only happens when it can be meaningful:
 
   * the entry point's arrays must be CONCRETE (under ``jit`` tracing the
-    inputs are tracers and nothing can be timed — the cache/table answer is
+    inputs are tracers and nothing can be timed — the cache/heuristic answer is
     used instead, so jitted callers compile against the persisted winner);
   * ``REPRO_AUTOTUNE`` gates it (default: on for compiled TPU kernels, off in
     interpret mode, where timings measure the interpreter's dispatch, not the
@@ -38,21 +41,15 @@ from repro.util import env_flag
 ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
 ENV_GATE = "REPRO_AUTOTUNE"
 
-# Measured-good block sizes from the PR-1 benchmark host, keyed
-# (R, N, d, m, dtype-name) — the FALLBACK when the measured cache has no
-# entry and measurement is gated off (tracing, interpret mode, disabled).
-STATIC_TABLE: dict[tuple[int, int, int, int, str], tuple[int, int]] = {
-    (4096, 8192, 64, 4, "float32"): (256, 64),
-    (4096, 8192, 64, 4, "bfloat16"): (256, 64),
-    (8192, 8192, 64, 4, "float32"): (256, 64),
-    (4096, 8192, 128, 4, "float32"): (256, 128),
-    (4096, 4096, 64, 4, "float32"): (512, 64),
-    (1024, 1024, 64, 4, "float32"): (256, 64),
-}
+# messages of a compile refusal for memory — the one failure a candidate
+# tiling may be skipped for
+_REFUSAL_MARKS = ("RESOURCE_EXHAUSTED", "Ran out of memory")
 
 # in-memory mirror of the JSON file, keyed by cache path so tests that
 # repoint REPRO_AUTOTUNE_CACHE never see another file's entries
 _MEM: dict[str, dict[str, list[int]]] = {}
+# (kind, shape_key, blocks, message) of every candidate refused for memory
+_REFUSALS: list[tuple[str, tuple, tuple, str]] = []
 
 
 def cache_path() -> pathlib.Path:
@@ -90,14 +87,14 @@ def _load(path: pathlib.Path) -> dict[str, list[int]]:
     except faults.DeviceLost:
         raise  # simulated preemption is fatal, not a degradation
     except (OSError, ValueError, faults.FaultInjected) as e:
-        # corrupt/unreadable cache: fall back to the static table — but
+        # corrupt/unreadable cache: fall back to the heuristic — but
         # recorded, not silent (a fleet quietly losing its tunings is an
         # operational smell worth surfacing)
         from repro.resilience.degrade import global_health
 
         entries = {}
         global_health().record(
-            "autotune.load", rung_from="measured-cache", rung_to="static-table",
+            "autotune.load", rung_from="measured-cache", rung_to="heuristic",
             detail=repr(e),
         )
     _MEM[key] = entries
@@ -141,15 +138,22 @@ def record(kind: str, shape_key: tuple, dtype, interpret: bool,
     _store(path, entries)
 
 
+def refusals() -> list[tuple[str, tuple, tuple, str]]:
+    """Candidate tilings skipped because the compiler refused them for
+    memory: ``(kind, shape_key, blocks, first line of the error)``."""
+    return list(_REFUSALS)
+
+
+def _is_memory_refusal(e: Exception) -> bool:
+    return any(mark in str(e) for mark in _REFUSAL_MARKS)
+
+
 def _time_once(fn) -> float:
-    """One warmup (compile) + one timed rep; failures rank last."""
-    try:
-        jax.block_until_ready(fn())
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        return time.perf_counter() - t0
-    except Exception:
-        return float("inf")
+    """One warmup (compile) + one timed rep."""
+    jax.block_until_ready(fn())
+    t0 = time.perf_counter()
+    jax.block_until_ready(fn())
+    return time.perf_counter() - t0
 
 
 def measured_blocks(
@@ -161,19 +165,32 @@ def measured_blocks(
 
     Resolution order: persisted/measured cache hit → (if ``concrete`` inputs
     and the gate allows) time ``bench_fn(blocks)`` for each candidate once,
-    persist and return the winner → ``fallback`` (the static table /
-    heuristic answer).  ``bench_fn`` runs the caller's actual kernel on its
-    actual arrays, so the measurement is of the real workload."""
+    persist and return the winner → ``fallback`` (the heuristic answer).
+    ``bench_fn`` runs the caller's actual kernel on its actual arrays, so the
+    measurement is of the real workload.  A candidate the compiler refuses
+    for memory is skipped (``refusals()``); any other exception propagates,
+    and so does the refusal when no candidate is left."""
     hit = lookup(kind, shape_key, dtype, interpret, arity=len(fallback))
     if hit is not None:
         return hit
     if not concrete or not measure_enabled() or not candidates:
         return fallback
     candidates = list(dict.fromkeys(candidates))
-    timings = [(_time_once(lambda c=c: bench_fn(c)), c) for c in candidates]
-    best_t, best = min(timings, key=lambda tc: tc[0])
-    if best_t == float("inf"):
-        return fallback
+    timings, refusal = [], None
+    for c in candidates:
+        try:
+            timings.append((_time_once(lambda c=c: bench_fn(c)), c))
+        except Exception as e:  # noqa: BLE001 — re-raised unless a memory refusal
+            if not _is_memory_refusal(e):
+                raise
+            refusal = e
+            _REFUSALS.append((kind, tuple(shape_key), tuple(c),
+                              str(e).strip().split("\n")[0]))
+    if not timings:
+        raise RuntimeError(
+            f"autotune {kind} {shape_key}: the compiler refused every "
+            f"candidate tiling {candidates}") from refusal
+    _, best = min(timings, key=lambda tc: tc[0])
     record(kind, shape_key, dtype, interpret, best)
     return best
 

@@ -8,14 +8,13 @@ This layer makes the kernels shape- and backend-agnostic:
     a (shape, dtype, backend) key times candidate tilings on the caller's
     real arrays and persists the winner to ``REPRO_AUTOTUNE_CACHE`` (default
     ``~/.cache/repro/autotune.json``); jitted/traced calls and disabled or
-    corrupt caches fall back to the static table + VMEM-budget heuristic
-    (``autotune.py``);
+    corrupt caches fall back to a fixed heuristic per entry point;
   * arbitrary shapes are zero-padded up to the block grid and sliced back
     (padded K rows/columns contribute nothing; padded sketch columns carry
     coef 0);
-  * wide K is chunked along columns with ``jax.lax.scan`` so the jaxpr stays
-    O(1) in the number of chunks — the seed's Python loop unrolled one
-    pallas_call per chunk under jit;
+  * K's columns (the contraction axis) are tiled inside the kernels' grids,
+    so any width is one pallas_call with a VMEM-sized K tile
+    (``_col_block``);
   * ``sketch_both_kernel`` exposes the fused (K S, SᵀK S) single-sweep kernel,
     ``sketch_left_kernel`` applies Sᵀ M through the true left-apply kernel
     (M streamed in row tiles — no Mᵀ copy);
@@ -45,7 +44,9 @@ from repro.kernels.accum_apply.kernel import (
 )
 from repro.util import env_flag
 
-MAX_COLS = 8192   # per-chunk K columns: bm·MAX_COLS·4B ≤ ~8MB VMEM at bm=256
+COL_TILE_BYTES = 2 * 1024 * 1024   # one (bm, bn) K tile; double-buffered 4 MiB
+LANES = 128                        # the lane tile: output column blocks are
+                                   # d itself or a multiple of it
 
 
 def default_interpret() -> bool:
@@ -57,41 +58,40 @@ def default_interpret() -> bool:
 
 def autotune_blocks(R: int, N: int, d: int, m: int, dtype,
                     *, interpret: bool | None = None) -> tuple[int, int]:
-    """(bm, bd) for the gather→GEMM kernel: measured-cache hit → static table
-    hit → VMEM-budget heuristic.
+    """(bm, bd) for the gather→GEMM kernels: measured-cache hit, else
+    (256, min(d, LANES)).
 
-    This is the TABLE side only — it never times anything, so it is safe at
+    This is the lookup side only — it never times anything, so it is safe at
     trace time.  The entry points below measure candidate tilings on their
     real (concrete) arrays via ``autotune.measured_blocks`` and persist the
     winner, which this lookup then serves to every later (including jitted)
-    call at the same (shape, dtype, backend) key.
-
-    Heuristic: keep the K tile ≤ ~8 MiB of VMEM (bm·min(N, MAX_COLS)·itemsize)
-    and make the GEMM lane dimension as wide as d allows (≤ 128 lanes)."""
+    call at the same (shape, dtype, backend) key.  The K column chunk that
+    bounds VMEM is derived from bm (``_col_block``), so no (bm, bd) pair can
+    overflow VMEM at any N."""
     if interpret is None:
         interpret = default_interpret()
     hit = autotune.lookup("accum_apply", (R, N, d, m), dtype, interpret,
                           arity=2)
     if hit is not None:
         return hit
-    key = (R, N, d, m, jnp.dtype(dtype).name)
-    if key in autotune.STATIC_TABLE:
-        return autotune.STATIC_TABLE[key]
-    itemsize = jnp.dtype(dtype).itemsize
-    ncols = min(N, MAX_COLS)
-    bm = max(8, min(256, (8 * 1024 * 1024) // max(ncols * itemsize, 1)))
-    bd = min(d, 128)
-    return bm, bd
+    return min(256, R), min(d, LANES)
+
+
+def _col_block(N: int, bm: int, itemsize: int) -> int:
+    """K column chunk bn for a bm-row tile: the (bm, bn) tile stays within
+    ``COL_TILE_BYTES`` (bn a multiple of 128 lanes); narrow K is one chunk."""
+    bn = max(128, COL_TILE_BYTES // (bm * itemsize) // 128 * 128)
+    return N if N <= bn else bn
 
 
 def _gemm_candidates(R: int, d: int, fallback: tuple[int, int]) -> list[tuple[int, int]]:
     """Candidate (bm, bd) tilings for the gather→GEMM family: the fallback
-    plus a taller and a shorter row tile (the lane dimension is d-bound)."""
-    bds = {fallback[1], min(d, 64), min(d, 128)}
+    plus a taller and a shorter row tile, and a wider lane tile.  bd is d
+    itself or a multiple of the 128-lane tile — the only output blocks
+    Mosaic accepts."""
+    bds = {fallback[1], min(d, 128), min(d, 256)}
     bms = {fallback[0], min(R, 128), min(R, 512)}
-    cands = [(bm, bd) for bm in sorted(bms) for bd in sorted(bds)
-             if bm >= 8 and bd >= 1]
-    return cands[:6]
+    return [(bm, bd) for bm in sorted(bms) for bd in sorted(bds)][:6]
 
 
 def _pad_rows(K: jax.Array, mult: int) -> jax.Array:
@@ -109,15 +109,23 @@ def _pad_sketch(idx: jax.Array, coef: jax.Array, mult: int):
     return idx, coef
 
 
+def _pad_cols(K: jax.Array, mult: int) -> jax.Array:
+    pad = (-K.shape[1]) % mult
+    return jnp.pad(K, ((0, 0), (0, pad))) if pad else K
+
+
 def _apply_padded(K, idx, coef, *, bm, bd, interpret):
-    """accum_apply on arbitrary (R, d): pad to the block grid, slice back."""
-    R, _ = K.shape
+    """accum_apply on arbitrary (R, N, d): pad to the block grid, slice back
+    (padded K columns are never indexed by the sketch)."""
+    R, N = K.shape
     d = idx.shape[1]
     bm_e = min(bm, R)
     bd_e = min(bd, d)
-    Kp = _pad_rows(K, bm_e)
+    bn = _col_block(N, bm_e, K.dtype.itemsize)
+    Kp = _pad_cols(_pad_rows(K, bm_e), bn)
     idx_p, coef_p = _pad_sketch(idx, coef, bd_e)
-    out = accum_apply(Kp, idx_p, coef_p, bm=bm_e, bd=bd_e, interpret=interpret)
+    out = accum_apply(Kp, idx_p, coef_p, bm=bm_e, bd=bd_e, bn=bn,
+                      interpret=interpret)
     return out[:R, :d]
 
 
@@ -125,9 +133,8 @@ def sketch_right_kernel(
     K: jax.Array, sk: AccumSketch, *, bm: int | None = None,
     bd: int | None = None, interpret: bool | None = None,
 ) -> jax.Array:
-    """K S via the Pallas kernel; wide K is `lax.scan`ned over column chunks
-    and the f32 partial products summed (the paper's accumulation identity).
-    The scan keeps the jaxpr a single pallas_call regardless of N."""
+    """K S via the Pallas kernel — one pallas_call at any width of K (the
+    kernel's grid tiles the contraction axis)."""
     faults.fault_point("kernel.dispatch")
     if interpret is None:
         interpret = default_interpret()
@@ -136,11 +143,9 @@ def sketch_right_kernel(
     coef = sk.coef.astype(jnp.float32)
     if bm is None and bd is None:
         fb = autotune_blocks(R, N, d, m, K.dtype, interpret=interpret)
-        # measure only the single-launch regime — the wide-K scan re-enters
-        # this function per chunk and would nest measurements
         bm, bd = autotune.measured_blocks(
             "accum_apply", (R, N, d, m), K.dtype, interpret,
-            _gemm_candidates(R, d, fb) if N <= MAX_COLS else [],
+            _gemm_candidates(R, d, fb),
             lambda c: _apply_padded(K, sk.indices, coef, bm=c[0], bd=c[1],
                                     interpret=interpret),
             fb, concrete=autotune.is_concrete(K, sk.indices, coef))
@@ -148,37 +153,7 @@ def sketch_right_kernel(
         a_bm, a_bd = autotune_blocks(R, N, d, m, K.dtype, interpret=interpret)
         bm = a_bm if bm is None else bm
         bd = a_bd if bd is None else bd
-    if N <= MAX_COLS:
-        return _apply_padded(K, sk.indices, coef, bm=bm, bd=bd,
-                             interpret=interpret)
-
-    def _chunk_sketch(lo, hi):
-        # indices outside [lo, hi) are redirected to column 0 with
-        # coefficient 0 — the partial products then sum to the exact result
-        inside = (sk.indices >= lo) & (sk.indices < hi)
-        idx_c = jnp.where(inside, sk.indices - lo, 0).astype(jnp.int32)
-        coef_c = jnp.where(inside, coef, 0.0)
-        return idx_c, coef_c
-
-    def body(acc, lo):
-        idx_c, coef_c = _chunk_sketch(lo, lo + MAX_COLS)
-        Kc = jax.lax.dynamic_slice_in_dim(K, lo, MAX_COLS, axis=1)
-        part = _apply_padded(Kc, idx_c, coef_c, bm=bm, bd=bd,
-                             interpret=interpret)
-        return acc + part.astype(jnp.float32), None
-
-    # scan the full-width chunks of K in place (no padded copy of K — this is
-    # exactly the path where K is too big to duplicate), then fold in the
-    # ragged tail chunk with one extra call
-    nfull = N // MAX_COLS
-    los = jnp.arange(nfull, dtype=jnp.int32) * MAX_COLS
-    acc, _ = jax.lax.scan(body, jnp.zeros((R, d), jnp.float32), los)
-    if N % MAX_COLS:
-        lo = nfull * MAX_COLS
-        idx_c, coef_c = _chunk_sketch(lo, N)
-        acc = acc + _apply_padded(K[:, lo:], idx_c, coef_c, bm=bm, bd=bd,
-                                  interpret=interpret).astype(jnp.float32)
-    return acc.astype(K.dtype)
+    return _apply_padded(K, sk.indices, coef, bm=bm, bd=bd, interpret=interpret)
 
 
 def sketch_left_kernel(
@@ -199,10 +174,11 @@ def sketch_left_kernel(
     d = sk.d
     coef = sk.coef.astype(jnp.float32)
     if bn is None:
-        # row tile bounded by ~8 MiB of VMEM for the M tile; the interpreter
-        # wants few large steps (per-step dispatch dominates there)
+        # the (bn, c) M tile and the (bn, d) one-hot block of S within
+        # ~2 MiB of f32 VMEM; the interpreter wants few large steps (per-step
+        # dispatch dominates there)
         bn = min(4096 if interpret else 2048,
-                 max(8, (2 * 1024 * 1024) // max(c, 1)))
+                 max(8, COL_TILE_BYTES // (4 * (c + d)) // 8 * 8))
     bn_e = min(bn, N)
     Mp = _pad_rows(M, bn_e)
     idx_p, coef_p = _pad_sketch(sk.indices, coef, min(8, max(d, 1)))
@@ -220,38 +196,25 @@ def sketch_step_kernel(
 
     The progressive engine's m → m+1 increment routes here so the column
     gather hits the MXU gather→GEMM path with the running C's rescale fused
-    in.  Arbitrary shapes are padded to the block grid and sliced back; K
-    wider than ``MAX_COLS`` falls back to the chunk-scanned ``accum_apply``
-    for the gather and applies the rescale outside the kernel."""
+    in.  Arbitrary shapes are padded to the block grid and sliced back."""
     if interpret is None:
         interpret = default_interpret()
     R, N = K.shape
     d = idx_row.shape[0]
     a_bm, a_bd = autotune_blocks(R, N, d, 1, K.dtype, interpret=interpret)
-    bm = a_bm if bm is None else bm
-    bd = a_bd if bd is None else bd
-    coef32 = coef_row.astype(jnp.float32)
-    a_arr = jnp.asarray(a, jnp.float32).reshape((1,))
-    if N > MAX_COLS:
-        # chunk-scan path: reuse the wide-K machinery on a one-slab sketch
-        one = AccumSketch(
-            indices=idx_row[None, :].astype(jnp.int32),
-            signs=jnp.sign(coef32)[None, :], probs=jnp.full((N,), 1.0 / N,
-                                                            jnp.float32),
-            n=N, coef_=coef32[None, :])
-        G = sketch_right_kernel(K, one, bm=bm, bd=bd, interpret=interpret)
-        return a_arr[0] * C + G.astype(C.dtype)
-    bm_e = min(bm, R)
-    bd_e = min(bd, d)
-    Kp = _pad_rows(K, bm_e)
+    bm_e = min(a_bm if bm is None else bm, R)
+    bd_e = min(a_bd if bd is None else bd, d)
+    bn = _col_block(N, bm_e, K.dtype.itemsize)
+    Kp = _pad_cols(_pad_rows(K, bm_e), bn)
     Cp = _pad_rows(C, bm_e)
     idx_p, coef_p = _pad_sketch(idx_row[None, :].astype(jnp.int32),
-                                coef32[None, :], bd_e)
+                                coef_row.astype(jnp.float32)[None, :], bd_e)
     dpad = idx_p.shape[1] - d
     if dpad:
         Cp = jnp.pad(Cp, ((0, 0), (0, dpad)))
+    a_arr = jnp.asarray(a, jnp.float32).reshape((1,))
     out = accum_step_slab(Kp, idx_p, coef_p, Cp, a_arr, bm=bm_e, bd=bd_e,
-                          interpret=interpret)
+                          bn=bn, interpret=interpret)
     return out[:R, :d]
 
 
@@ -283,7 +246,7 @@ def accum_grow_kernel(
         bm_e, bn_e = min(blocks[0], R), min(blocks[1], N)
         rpad, cpad = (-R) % bm_e, (-N) % bn_e
         Kp = jnp.pad(K, ((0, rpad), (0, cpad))) if (rpad or cpad) else K
-        idx_p, coef_p = _pad_sketch(idx32, coef32, min(8, max(d, 1)))
+        idx_p, coef_p = _pad_sketch(idx32, coef32, min(d, LANES))
         dpad = idx_p.shape[1] - d
         Cp = _pad_rows(C, bm_e)
         if dpad:
@@ -305,63 +268,40 @@ def accum_grow_kernel(
     return run((bm, bn))
 
 
-def expand_coef(coef: jax.Array, d: int) -> jax.Array:
-    """(m, d) combination coefficients → the (m·d, d) block-sparse matrix Cmat
-    with Cmat[i·d + j, j] = coef[i, j], so that S = E·Cmat for the (n, m·d)
-    landmark selection matrix E and K S = K(·, landmarks)·Cmat.  Zero rows
-    (padding) select nothing."""
-    m = coef.shape[0]
-    md = m * d
-    cols = jnp.tile(jnp.arange(d), m)
-    return (
-        jnp.zeros((md, d), jnp.float32)
-        .at[jnp.arange(md), cols]
-        .set(coef.reshape(-1).astype(jnp.float32))
-    )
-
-
 def matfree_cols_kernel(
     Xq: jax.Array, landmarks: jax.Array, coef: jax.Array, *, kernel: str,
     bandwidth: float = 1.0, nu: float = 1.5, bm: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """C = K(Xq, X)·S straight from data rows via the fused Pallas kernel —
-    the (tile, m·d) kernel block is evaluated in VMEM and contracted with the
-    coefficient block in the same grid step; no n×n object ever exists.
+    each sub-sketch's (tile, d) kernel block is evaluated in VMEM and scaled
+    by its coefficients in the same grid step; no n×n object ever exists.
 
     Xq: (nq, p) query rows; landmarks: (m·d, p) sampled rows X[sk.indices];
-    coef: (m, d).  Arbitrary nq is row-padded to the tile and sliced back;
-    the landmark count is sublane-padded with zero rows (zero coefficient
-    rows contribute nothing).  Returns (nq, d) float32."""
+    coef: (m, d).  Arbitrary nq is row-padded to the tile and sliced back.
+    Returns (nq, d) float32."""
     faults.fault_point("kernel.dispatch")
     if interpret is None:
         interpret = default_interpret()
     nq, p = Xq.shape
     m, d = coef.shape
-    Cmat = expand_coef(coef, d)
-    pad_md = (-(m * d)) % 8
-    if pad_md:
-        landmarks = jnp.pad(landmarks, ((0, pad_md), (0, 0)))
-        Cmat = jnp.pad(Cmat, ((0, pad_md), (0, 0)))
-    pad_d = (-d) % 8
-    if pad_d:
-        Cmat = jnp.pad(Cmat, ((0, 0), (0, pad_d)))
+    L = landmarks.reshape(m, d, p)
 
     def run(blocks):
         bm_e = min(blocks[0], nq)
         Xp = _pad_rows(Xq, bm_e)
-        out = matfree_apply(Xp, landmarks, Cmat, kernel=kernel,
-                            bandwidth=bandwidth, nu=nu, bm=bm_e,
-                            interpret=interpret)
-        return out[:nq, :d]
+        out = matfree_apply(Xp, L, coef, kernel=kernel, bandwidth=bandwidth,
+                            nu=nu, bm=bm_e, interpret=interpret)
+        return out[:nq]
 
     if bm is None:
-        # heuristic fallback: keep the f32 (bm, md) kernel slab + (bm, p)
-        # tile ≲ 8 MiB of VMEM
-        fb = (max(8, min(1024, (2 * 1024 * 1024) // max(m * d + p, 1))),)
+        # heuristic fallback: the few live f32 (bm, d) slabs ≲ 2 MiB of VMEM,
+        # bm a multiple of the 8-row sublane tile; measuring tries taller
+        # tiles and skips the ones the compiler refuses
+        fb = min(512, max(8, (128 * 1024) // max(d, 1) // 8 * 8))
         (bm,) = autotune.measured_blocks(
             "matfree_cols", (nq, p, d, m, kernel), Xq.dtype, interpret,
-            [fb, (min(nq, 256),), (min(nq, 1024),)], run, fb,
+            [(min(nq, b),) for b in (fb, 2 * fb, 4 * fb)], run, (fb,),
             concrete=autotune.is_concrete(Xq, landmarks, coef))
     return run((bm,))
 
@@ -399,7 +339,7 @@ def sketch_both_kernel(
     assert n == n2, "sketch_both_kernel expects square K"
     d = sk.d
     coef = sk.coef.astype(jnp.float32)
-    idx_p, coef_p = _pad_sketch(sk.indices, coef, min(8, max(sk.d, 1)))
+    idx_p, coef_p = _pad_sketch(sk.indices, coef, min(d, LANES))
 
     def run(blocks):
         bm_e, bn_e = min(blocks[0], n), min(blocks[1], n)

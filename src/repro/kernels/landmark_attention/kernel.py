@@ -33,20 +33,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.kernels_math import f32_dot
+
+# `landmark_attention` upcasts every operand to f32 and contracts at f32
+# precision (``f32_dot``): at the TPU's default precision sketched decode
+# would disagree with exact f32 attention at ~1e-3 where its slots are
+# singletons.  (`landmark_stats` feeds an approximate Newton–Schulz
+# pseudo-inverse and keeps the default: at f32 precision its L = 1024 tiles
+# overflow the 16 MiB scoped VMEM.)
+
 
 def _kernel(q_ref, kt_ref, M_ref, b_ref, out_ref, *, scale: float):
     q = q_ref[...].astype(jnp.float32)
     kt = kt_ref[...].astype(jnp.float32)
-    logits = jax.lax.dot_general(
-        q, kt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale + b_ref[...]                                # (bq, L) + (1, L)
+    logits = f32_dot(q, kt, ((1,), (1,))) * scale + b_ref[...]  # (bq, L)
     mx = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - mx)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out = jax.lax.dot_general(
-        p, M_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out = f32_dot(p, M_ref[...].astype(jnp.float32))
     out_ref[...] = out.astype(out_ref.dtype)
 
 

@@ -19,9 +19,10 @@ Terms (per chip — the SPMD module is the per-partition program):
   compute    = FLOPs / hw.peak_flops     memory = bytes / hw.hbm_bw
   collective = coll_bytes / hw.ici_bw
 
-The chip numbers live in `repro.analysis.hardware.HardwareModel` (default:
-TPU v5e-class) — `Roofline` carries the model it was scored against, and
-`set_default_hardware` swaps the target chip process-wide.
+The chip numbers live in the peak table of `repro.analysis.hardware`, keyed
+by `device_kind` — `Roofline` carries the model it was scored against, and
+without one it scores against the chip the process runs on (an unknown kind
+raises).
 """
 from __future__ import annotations
 
@@ -378,7 +379,7 @@ class Roofline:
     peak_mem_bytes: float        # per-chip peak allocation (memory_analysis)
     xla_flops: float = 0.0       # raw cost_analysis (uncorrected, for reference)
     xla_bytes: float = 0.0
-    hardware: HardwareModel | None = None   # None → process default
+    hardware: HardwareModel | None = None   # None → the chip in use
 
     @property
     def hw(self) -> HardwareModel:

@@ -22,6 +22,7 @@ import jax
 
 from repro.configs import ARCHS
 from repro.configs.base import SHAPES
+from repro.analysis.hardware import TPU_V5E
 from repro.launch.analysis import analyze, model_flops
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import make_cell
@@ -41,7 +42,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, tc: TrainConfig | N
     t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    roof = analyze(compiled)
+    roof = analyze(compiled, hardware=TPU_V5E)   # the production meshes are v5e
     shape = SHAPES[shape_name]
     n_active = _active_params(arch)
     n_tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.models.model import init_params
 from repro.serve.engine import Engine, ServeConfig
+from repro.util import use_compile_cache
 
 
 def main(argv=None) -> int:
@@ -29,6 +30,7 @@ def main(argv=None) -> int:
                     "context-length independent)")
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

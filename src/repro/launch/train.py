@@ -25,6 +25,7 @@ from repro.optim.adamw import AdamWConfig
 from repro.optim.compress import CompressConfig
 from repro.train.loop import LoopConfig, run
 from repro.train.step import TrainConfig, init_train_state, train_step
+from repro.util import use_compile_cache
 
 
 def main(argv=None) -> int:
@@ -43,6 +44,7 @@ def main(argv=None) -> int:
                     help="sketched gradient all-reduce compression (paper technique)")
     ap.add_argument("--mesh", choices=["debug", "pod", "multipod"], default="debug")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

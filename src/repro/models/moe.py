@@ -122,11 +122,6 @@ def _moe_forward_a2a(p, h: jax.Array, moe: MoECfg, mesh, dp, ep: str):
     are all-gathered over dp here (ZeRO-3 gather, transposed by autodiff into
     a reduce-scatter of the grads) so each data shard contracts its own
     tokens against full-D weights."""
-    # jax.shard_map only exists on newer jax; 0.4.x ships it in experimental
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     B, S, D = h.shape
     E, K = moe.n_experts, moe.top_k
     M = mesh.shape[ep]
@@ -175,11 +170,7 @@ def _moe_forward_a2a(p, h: jax.Array, moe: MoECfg, mesh, dp, ep: str):
         return out.reshape(B // dp_size, S, D), aux, dropped
 
     dp_spec = dp if len(dp) != 1 else dp[0]
-    # newer jax renamed check_rep → check_vma; support both
-    import inspect
-    _chk = ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-            else "check_rep")
-    out, aux, dropped = shard_map(
+    out, aux, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(
             P_(dp_spec, None, None),              # h: batch over dp
@@ -189,7 +180,7 @@ def _moe_forward_a2a(p, h: jax.Array, moe: MoECfg, mesh, dp, ep: str):
             P_(ep, None, dp_spec),                # wo (E, F, D)
         ),
         out_specs=(P_(dp_spec, None, None), P_(), P_()),
-        **{_chk: False},
+        check_vma=False,
     )(h, p["router"].astype(jnp.float32), p["wi_gate"], p["wi_up"], p["wo"])
     return out, aux, dropped
 

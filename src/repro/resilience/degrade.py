@@ -2,10 +2,13 @@
 
 A *ladder* is an ordered list of implementations of the same computation,
 fastest first: Pallas kernel → XLA ``lax.scan`` path → dense oracle.  When a
-rung raises, :func:`ladder_call` records the degradation in a
-:class:`HealthReport` and falls to the next rung — the result stays correct,
-only slower, and the event is surfaced through ``info`` / engine stats
-instead of silently changing numerics.
+rung raises an injected fault (:class:`faults.FaultInjected`),
+:func:`ladder_call` records the degradation in a :class:`HealthReport` and
+falls to the next rung — the result stays correct, only slower, and the event
+is surfaced through ``info`` / engine stats.  Every other exception — a
+Mosaic compile or lowering error, a shape bug — propagates: the ladder
+exists to exercise recovery from faults, never to hide a kernel that does
+not run on the device.
 
 For numerics that fail *inside* jitted code (a Cholesky on a non-PSD
 matrix), :func:`solve_psd_ladder` runs the whole ladder — escalating ×10
@@ -17,8 +20,14 @@ entry in ``analysis/contracts.toml``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable, Sequence
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.scipy.linalg import cho_factor, cho_solve
 
 from repro.resilience import faults
 
@@ -90,23 +99,22 @@ def ladder_call(
     *,
     health: HealthReport | None = None,
 ):
-    """Run ``rungs`` (``(name, thunk)`` pairs, fastest first) until one succeeds.
+    """Run ``rungs`` (``(name, thunk)`` pairs, fastest first) until one
+    succeeds or fails with something other than an injected fault.
 
     ``site`` names the ladder for health records; fault *arrivals* happen
     inside the rungs themselves (the kernel entry points in
     ``kernels/*/ops.py`` visit ``kernel.dispatch``, the streaming rung visits
     ``kernel.stream``), so arming both sites drives a three-rung ladder all
     the way to its dense oracle.  Each drop is recorded in ``health``
-    (default: the global report).  The terminal rung's exception — and any
-    :class:`faults.DeviceLost`, which models preemption, not a backend bug —
-    propagates."""
+    (default: the global report).  Only :class:`faults.FaultInjected` drops
+    a rung; the terminal rung's fault, any :class:`faults.DeviceLost` (a
+    preemption, not a degradation) and every real exception propagate."""
     hr = health if health is not None else _GLOBAL
     for i, (name, fn) in enumerate(rungs):
         try:
             return fn()
-        except faults.DeviceLost:
-            raise
-        except Exception as e:  # noqa: BLE001 — the ladder exists to catch rung failures
+        except faults.FaultInjected as e:
             if i == len(rungs) - 1:
                 raise
             hr.record(site, rung_from=name, rung_to=rungs[i + 1][0], detail=repr(e))
@@ -126,12 +134,16 @@ def solve_psd_ladder(M, b, *, escalations: int = 3):
     The ``solve.cholesky`` fault site mangles ``M`` on entry (eager calls
     only; tracers pass through), letting fault-plan tests drive both the
     escalation rung (tiny ``scale``) and the lstsq rung (large ``scale``).
+    The ladder itself is one jitted program: called eagerly, its loop and
+    cond closures would otherwise be traced and compiled again on every
+    call (seconds on a TPU, where the lstsq rung is an SVD).
     """
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.scipy.linalg import cho_factor, cho_solve
-
     M = faults.mangle_matrix("solve.cholesky", M)
+    return _solve_psd_ladder(M, b, escalations=escalations)
+
+
+@functools.partial(jax.jit, static_argnames=("escalations",))
+def _solve_psd_ladder(M, b, *, escalations: int):
     d = M.shape[0]
     eye = jnp.eye(d, dtype=M.dtype)
     j0 = 1e-8 * (jnp.trace(M) / d + 1e-30)

@@ -36,6 +36,7 @@ Resilience (see docs/resilience.md):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 from typing import Any
@@ -97,6 +98,47 @@ class ServeConfig:
     health_check: bool = True
 
 
+def _slots(cfg: ModelConfig, sc: ServeConfig, slot_key, pos) -> jax.Array:
+    sa = cfg.sketch_attn
+    return decode_slots(
+        slot_key, pos, sa.d_slots, sa.m_r,
+        scheme=sc.slot_scheme, max_len=sc.max_len,
+    )
+
+
+def _sample(sc: ServeConfig, sample_key, logits: jax.Array, pos) -> jax.Array:
+    if sc.temperature <= 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    k = jax.random.fold_in(sample_key, pos)  # rng-stream: sample-position
+    return jax.random.categorical(k, logits / sc.temperature).astype(jnp.int32)
+
+
+def _decode_scan(
+    cfg: ModelConfig, sc: ServeConfig, slot_key, sample_key, params, cache,
+    tok0, pos0, *, n_steps: int, use_sketch: bool | None = None,
+):
+    """n_steps decode steps + samples as one jitted `lax.scan` dispatch.
+
+    `use_sketch` (static) overrides the engine default so a degraded
+    request can continue on the exact-attention path."""
+    if use_sketch is None:
+        use_sketch = sc.use_sketch
+
+    def _body(carry, _):
+        cache, tok, pos = carry
+        logits, cache = decode_step(
+            params, cache, tok, pos, cfg,
+            slots=_slots(cfg, sc, slot_key, pos), use_sketch=use_sketch,
+        )
+        nxt = _sample(sc, sample_key, logits, pos + 1)
+        return (cache, nxt, pos + 1), nxt
+
+    (cache, _, _), toks = jax.lax.scan(
+        _body, (cache, tok0, pos0), None, length=n_steps
+    )
+    return jnp.swapaxes(toks, 0, 1), cache
+
+
 class Engine:
     """Single-host engine; the sharded variant jits with in_shardings from
     repro.sharding (see launch/serve.py)."""
@@ -115,8 +157,18 @@ class Engine:
         self._prefill = jax.jit(
             lambda p, c, t, st: prefill_with_cache(p, t, cfg, c, slot_table=st)
         )
+        # the cache is donated: the scan's carry reuses its buffers, so one
+        # copy of the cache is resident, not two (at stablelm-3b width, B=4
+        # and a 2k context the second copy does not fit a 16 GB chip).  It
+        # jits a partial, not a bound method: that would hold the engine, and
+        # through it the weights, in a reference cycle that outlives `del
+        # engine` until the next cyclic collection.
         self._decode = jax.jit(
-            self._decode_scan, static_argnames=("n_steps", "use_sketch")
+            functools.partial(
+                _decode_scan, cfg, sc, self._slot_key, self._sample_key
+            ),
+            static_argnames=("n_steps", "use_sketch"),
+            donate_argnums=(1,),
         )
 
     def new_cache(self, batch: int, use_sketch: bool | None = None) -> DecodeCache:
@@ -135,11 +187,7 @@ class Engine:
         return {"health_events": self.health.count(), "health": self.health.summary()}
 
     def _slots(self, pos) -> jax.Array:
-        sa = self.cfg.sketch_attn
-        return decode_slots(
-            self._slot_key, pos, sa.d_slots, sa.m_r,
-            scheme=self.sc.slot_scheme, max_len=self.sc.max_len,
-        )
+        return _slots(self.cfg, self.sc, self._slot_key, pos)
 
     def _slot_table(self, length: int) -> jax.Array:
         sa = self.cfg.sketch_attn
@@ -175,26 +223,10 @@ class Engine:
     def _decode_scan(
         self, params, cache, tok0, pos0, *, n_steps: int, use_sketch: bool | None = None
     ):
-        """n_steps decode steps + samples as one jitted `lax.scan` dispatch.
-
-        `use_sketch` (static) overrides the engine default so a degraded
-        request can continue on the exact-attention path."""
-        if use_sketch is None:
-            use_sketch = self.sc.use_sketch
-
-        def _body(carry, _):
-            cache, tok, pos = carry
-            logits, cache = decode_step(
-                params, cache, tok, pos, self.cfg,
-                slots=self._slots(pos), use_sketch=use_sketch,
-            )
-            nxt = self._sample(logits, pos + 1)
-            return (cache, nxt, pos + 1), nxt
-
-        (cache, _, _), toks = jax.lax.scan(
-            _body, (cache, tok0, pos0), None, length=n_steps
+        return _decode_scan(
+            self.cfg, self.sc, self._slot_key, self._sample_key, params, cache,
+            tok0, pos0, n_steps=n_steps, use_sketch=use_sketch,
         )
-        return jnp.swapaxes(toks, 0, 1), cache
 
     # ---------------------------------------------------------------- resume
 
@@ -369,7 +401,4 @@ class Engine:
         return toks_done[:, :n_new], cache
 
     def _sample(self, logits: jax.Array, pos) -> jax.Array:
-        if self.sc.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        k = jax.random.fold_in(self._sample_key, pos)  # rng-stream: sample-position
-        return jax.random.categorical(k, logits / self.sc.temperature).astype(jnp.int32)
+        return _sample(self.sc, self._sample_key, logits, pos)

@@ -8,7 +8,7 @@ import pytest
 @pytest.fixture(autouse=True)
 def _autotune_isolation(tmp_path, monkeypatch):
     """Point the measured autotune cache at a per-test throwaway file: tests
-    asserting static-table block sizes must not read (or write) the user's
+    asserting heuristic block sizes must not read (or write) the user's
     persisted ~/.cache/repro/autotune.json.  Tests that exercise the cache
     explicitly monkeypatch their own path on top of this."""
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "autotune.json"))

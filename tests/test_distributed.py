@@ -125,6 +125,14 @@ def test_sharded_sketch_both_matches_single_device(n):
         # per-device peak: each shard holds exactly n/8 rows of C
         shapes = {s.data.shape for s in C1.addressable_shards}
         assert shapes == {(n // 8, d)}
+    # the padded C the sharded fit reduces: one ⌈n/8⌉-row tile per device,
+    # zero rows past n
+    Cp, _ = D.sharded_sketch_both(op, sk, mesh, padded=True)
+    rows = -(-n // 8)
+    assert Cp.shape == (8 * rows, d)
+    assert sorted((s.device.id, s.data.shape) for s in Cp.addressable_shards) == [
+        (dev.id, (rows, d)) for dev in sorted(mesh.devices.flat, key=lambda x: x.id)]
+    assert _rel(Cp[:n], C0) < 1e-5 and not np.asarray(Cp[n:]).any()
 
 
 @needs_8

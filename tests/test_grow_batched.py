@@ -11,7 +11,7 @@ The load-bearing guarantees (ISSUE 5 acceptance criteria):
   * one K-pass per batch — jaxpr regressions: a single pallas_call where the
     sequential loop launches B, and no B×(n·d) slab on the streaming path;
   * the measured autotune cache round-trips, and a corrupt/missing cache
-    falls back to the static table;
+    falls back to the heuristic blocks;
   * the engine's donated growth wrappers really alias their loop carries.
 """
 import json
@@ -61,8 +61,9 @@ def _problem(n=300, p=3, bandwidth=0.6, dtype=jnp.float32):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n,d,B", [(256, 16, 4), (300, 8, 8), (128, 64, 1),
-                                   (173, 9, 3)])
+                                   (173, 9, 3), (256, 300, 3)])
 def test_grow_kernel_sweep(n, d, B, dtype):
+    """d = 300 spans three 128-lane output column blocks (the last padded)."""
     K = jax.random.normal(jax.random.fold_in(KEY, n + d), (n, n), dtype)
     idx = jax.random.randint(jax.random.fold_in(KEY, 1), (B, d), 0, n)
     coef = jax.random.normal(jax.random.fold_in(KEY, 2), (B, d))
@@ -124,7 +125,7 @@ def test_batched_equals_sequential_f32(path, use_kernel, B):
 
 @pytest.mark.parametrize("path", ["dense", "matfree"])
 def test_batched_equals_sequential_f64_cpu(path):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         n, d, B = 200, 12, 4
         X = jax.random.uniform(KEY, (n, 3), jnp.float64)
         op = KernelOperator(X, "gaussian", bandwidth=0.6)
@@ -447,7 +448,7 @@ def test_autotune_corrupt_and_missing_cache_fall_back(tmp_path, monkeypatch):
     monkeypatch.setenv(autotune.ENV_CACHE, str(cache))
     monkeypatch.setenv(autotune.ENV_GATE, "0")         # no measuring
 
-    # missing file → static table hit at the anchor shape
+    # missing file → heuristic blocks at the anchor shape
     assert autotune_blocks(4096, 8192, 64, 4, jnp.float32, interpret=True) == (256, 64)
 
     # corrupt JSON → same fallback, no exception
@@ -502,3 +503,39 @@ def test_autotune_record_lookup_round_trip(tmp_path, monkeypatch):
     # and the fused-kernel table consults it
     from repro.kernels.accum_apply.ops import autotune_both_blocks
     assert autotune_both_blocks(512, True, 16, 4) == (128, 512)
+
+
+def test_autotune_skips_memory_refusals_only(tmp_path, monkeypatch):
+    """A candidate tiling the compiler refuses for memory is skipped and
+    kept in ``refusals()``; any other failure propagates; a measurement in
+    which every candidate is refused raises instead of returning the
+    fallback tiling."""
+    monkeypatch.setenv(autotune.ENV_CACHE, str(tmp_path / "a.json"))
+    monkeypatch.setenv(autotune.ENV_GATE, "1")
+    oom = "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"
+
+    def bench(c):
+        if c == (512,):
+            raise RuntimeError(oom)
+        return jnp.zeros(())
+
+    n0 = len(autotune.refusals())
+    got = autotune.measured_blocks("probe", (1,), jnp.float32, True,
+                                   [(512,), (256,)], bench, (8,), concrete=True)
+    assert got == (256,)
+    assert autotune.refusals()[n0:] == [("probe", (1,), (512,), oom)]
+
+    def misaligned(c):
+        raise ValueError("block shape (253, 90) not divisible by 8")
+
+    with pytest.raises(ValueError, match="divisible"):
+        autotune.measured_blocks("probe", (2,), jnp.float32, True,
+                                 [(253,), (256,)], misaligned, (8,), concrete=True)
+
+    def refuse(c):
+        raise RuntimeError(oom)
+
+    with pytest.raises(RuntimeError, match="refused every candidate"):
+        autotune.measured_blocks("probe", (3,), jnp.float32, True,
+                                 [(512,), (1024,)], refuse, (8,), concrete=True)
+    assert autotune.lookup("probe", (3,), jnp.float32, True) is None

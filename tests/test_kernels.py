@@ -9,7 +9,6 @@ from repro.analysis.trace import all_shapes
 from repro.core.sketch import make_accum_sketch
 from repro.core.sketched_attention import accum_attention, make_seq_sketch
 from repro.kernels.accum_apply.ops import (
-    MAX_COLS,
     autotune_blocks,
     default_interpret,
     sketch_both_kernel,
@@ -29,6 +28,7 @@ from repro.kernels.landmark_attention.ref import (
 )
 
 KEY = jax.random.PRNGKey(0)
+WIDE = 8192   # wider than one K column tile at the rows these tests use
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -47,7 +47,8 @@ def test_accum_apply_sweep(R, N, d, m, dtype):
 
 
 def test_accum_apply_wide_K_chunked():
-    """N > MAX_COLS path: chunked partial products sum exactly."""
+    """K wider than one column tile: the kernel's contraction grid sums the
+    chunk partial products exactly."""
     K = jax.random.normal(KEY, (128, 3 * 8192 // 2), jnp.float32)
     sk = make_accum_sketch(KEY, K.shape[1], 16, 4)
     ref = accum_apply_ref(K, sk.indices, sk.coef)
@@ -56,8 +57,8 @@ def test_accum_apply_wide_K_chunked():
 
 
 def test_accum_apply_wide_K_non_multiple_chunk():
-    """N neither a multiple of MAX_COLS nor of the block: scan + padding."""
-    N = 2 * MAX_COLS + 777
+    """N neither a multiple of the column tile nor of the block: padding."""
+    N = 2 * WIDE + 777
     K = jax.random.normal(KEY, (96, N), jnp.float32)
     sk = make_accum_sketch(jax.random.fold_in(KEY, 5), N, 12, 3)
     ref = accum_apply_ref(K, sk.indices, sk.coef)
@@ -75,9 +76,9 @@ def test_accum_apply_odd_shapes_padded():
 
 
 def test_wide_K_chunking_does_not_unroll():
-    """Jaxpr-size regression: the lax.scan chunk loop keeps the traced program
-    O(1) in the number of chunks (the seed's Python loop emitted one
-    pallas_call per chunk, exploding compile time for wide K)."""
+    """Jaxpr-size regression: the kernel's grid tiles the columns, so the
+    traced program is O(1) in the number of column chunks (the seed's Python
+    loop emitted one pallas_call per chunk, exploding compile time)."""
 
     def n_eqns(N):
         sk = make_accum_sketch(KEY, N, 16, 2)
@@ -86,15 +87,16 @@ def test_wide_K_chunking_does_not_unroll():
         )
         return len(jaxpr.jaxpr.eqns)
 
-    assert n_eqns(2 * MAX_COLS) == n_eqns(4 * MAX_COLS)
+    assert n_eqns(2 * WIDE) == n_eqns(4 * WIDE)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "n,d,m", [(128, 8, 1), (256, 32, 4), (128, 16, 8), (256, 64, 2)]
+    "n,d,m", [(128, 8, 1), (256, 32, 4), (128, 16, 8), (256, 64, 2), (256, 300, 3)]
 )
 def test_sketch_both_fused_sweep(n, d, m, dtype):
-    """Fused (C, W) kernel vs the two-pass oracle across shapes × dtypes."""
+    """Fused (C, W) kernel vs the two-pass oracle across shapes × dtypes;
+    d = 300 spans three 128-lane output column blocks (the last padded)."""
     K = jax.random.normal(KEY, (n, n), dtype)
     K = (0.5 * (K.astype(jnp.float32) + K.astype(jnp.float32).T)).astype(dtype)
     sk = make_accum_sketch(jax.random.fold_in(KEY, n + d * m), n, d, m)
@@ -187,6 +189,18 @@ def test_interpret_autodetect_and_autotune():
     # heuristic fallback stays within the VMEM budget and divides nothing
     bm, bd = autotune_blocks(1000, 5000, 48, 3, jnp.float32)
     assert bm >= 8 and 1 <= bd <= 48
+
+
+@pytest.mark.parametrize("d", [16, 64, 128, 200, 1024])
+def test_gemm_autotune_candidates_are_lane_legal(d):
+    """Every K·S tiling autotune may try has an output block Mosaic accepts:
+    bd is d itself or a multiple of the 128-lane tile (a (bm, 64) block of
+    a d = 128 output is refused at lowering, and autotune re-raises that)."""
+    from repro.kernels.accum_apply.ops import _gemm_candidates
+
+    fb = autotune_blocks(4096, 4096, d, 4, jnp.float32, interpret=True)
+    for bm, bd in _gemm_candidates(4096, d, fb):
+        assert bm % 8 == 0 and (bd == d or bd % 128 == 0), (bm, bd)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -3,6 +3,7 @@ empirics), leverage scores, incoherence, and K-satisfiability."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     get_kernel,
@@ -290,3 +291,21 @@ def test_sketched_krr_operator_models_share_treedef():
     out = jax.vmap(lambda m: m.predict(Xt))(stacked)
     np.testing.assert_allclose(np.asarray(out[1]), np.asarray(m2.predict(Xt)),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [5, 64, 65, 200, 1000])
+def test_f32_gram_blocks_and_tail(n):
+    """The fit's blocked, compensated Gram equals AᵀB at every row count:
+    one block, an exact multiple of the block, and a clamped last block
+    whose shared rows must not be summed twice."""
+    from repro.core.kernels_math import f32_gram
+
+    rng = np.random.default_rng(n)
+    a = rng.standard_normal((n, 7)).astype(np.float32) + 3.0
+    b = rng.standard_normal((n, 3)).astype(np.float32)
+    ref = a.astype(np.float64).T @ b.astype(np.float64)
+    scale = np.abs(a).T.astype(np.float64) @ np.abs(b)
+    got = np.asarray(f32_gram(jnp.asarray(a), jnp.asarray(b), rows=64))
+    assert np.max(np.abs(got - ref) / scale) < 1e-6
+    hi, lo = f32_gram(jnp.asarray(a), jnp.asarray(b), rows=64, parts=True)
+    np.testing.assert_array_equal(np.asarray(hi + lo), got)

@@ -110,7 +110,7 @@ def test_golden_dense_equals_matfree_f32(kernel, bw, nu):
 
 @pytest.mark.parametrize("kernel,bw,nu", KERNELS)
 def test_golden_dense_equals_matfree_f64_cpu(kernel, bw, nu):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _golden_case(kernel, bw, nu, jnp.float64)
 
 
@@ -219,7 +219,7 @@ def test_engine_grow_operator_equals_dense():
 def test_engine_grow_operator_f64_mode():
     """x64 regression: an f64 operator must not promote the engine's f32 loop
     carry (the fori/while carry dtype check rejects the step otherwise)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         n, d = 96, 8
         X = jax.random.uniform(KEY, (n, 3), jnp.float64)
         op = KernelOperator(X, "gaussian", bandwidth=0.6)

@@ -86,10 +86,7 @@ def test_truncated_state_sketch_consistent_with_from_scratch():
 # --------------------------------------------------------------------------- #
 
 def _iter_eqns(jaxpr):
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:  # older jax
-        from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(val):
         if isinstance(val, ClosedJaxpr):

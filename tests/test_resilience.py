@@ -338,6 +338,20 @@ class TestLadders:
         with pytest.raises(faults.FaultInjected):
             ladder_call("kernel.dispatch", rungs, health=HealthReport())
 
+    def test_ladder_propagates_real_errors(self):
+        """A rung that fails with anything but an injected fault — a Mosaic
+        compile or lowering error, a shape bug — propagates: no drop to a
+        slower rung, no health record."""
+        hr = HealthReport()
+
+        def broken():
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            ladder_call("kernel.dispatch", [("pallas", broken), ("xla", lambda: 42)],
+                        health=hr)
+        assert hr.count() == 0
+
     def test_ladder_lets_device_lost_fly(self, monkeypatch):
         """A simulated preemption is NOT a degradation — the ladder must not
         absorb it into a slower rung."""
@@ -396,7 +410,7 @@ class TestLadders:
         assert bool(fit.info["solve_used_lstsq"])
 
     def test_autotune_corrupt_cache_degrades(self, monkeypatch, tmp_path):
-        """A garbage cache file must fall back to the static table (lookup
+        """A garbage cache file must fall back to the heuristic (lookup
         returns None) and record the degradation — never crash the caller."""
         p = tmp_path / "autotune.json"
         p.write_text("{ this is not json")

@@ -148,6 +148,27 @@ def test_generate_runs_exactly_n_minus_1_decode_steps(built, monkeypatch, n_new,
     assert counter["n"] == expect
 
 
+@pytest.mark.parametrize("use_sketch", [False, True])
+def test_engine_freed_on_del_without_cyclic_gc(built, use_sketch):
+    """A served engine holds no reference cycle: dropping the last reference
+    frees it — and with it the weights it holds — at once, not at the next
+    cyclic collection (which may come after the next model's weights were
+    put on the device)."""
+    import gc
+    import weakref
+
+    cfg, params = built
+    eng = Engine(cfg, params, ServeConfig(max_len=32, use_sketch=use_sketch))
+    eng.generate(_prompts(1, 8, cfg.vocab_size), 3)
+    ref = weakref.ref(eng)
+    gc.disable()
+    try:
+        del eng
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
 # --------------------------------------------------------------------------- #
 # RNG streams (regression: slot draws and sampling shared fold_in(key, pos))
 # --------------------------------------------------------------------------- #

@@ -186,3 +186,21 @@ def test_engine_greedy_deterministic():
     out2, _ = eng.generate(prompts, 5)
     np.testing.assert_array_equal(out1, out2)
     assert out1.shape == (1, 5)
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch):
+    """Entry points keep the persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, else at a fixed, git-ignored
+    ``.jax_cache/`` in the checkout (only the path is resolved here — tests
+    never turn the cache on)."""
+    import pathlib
+
+    from repro.util import CHECKOUT_ROOT, compile_cache_dir
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == str(CHECKOUT_ROOT / ".jax_cache")
+    assert (CHECKOUT_ROOT / "src" / "repro" / "util.py").is_file()
+    ignored = (pathlib.Path(CHECKOUT_ROOT) / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
