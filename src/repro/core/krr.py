@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.core import apply as A
 from repro.core.kernels_math import f32_gram, f32_matmul
 from repro.core.sketch import AccumSketch
+from repro.spans import phase
 
 
 def _solve_psd(M: jax.Array, b: jax.Array) -> jax.Array:
@@ -103,6 +104,7 @@ class SketchedKRR:
         return cls(theta=theta, sk=sk, S_dense=S_dense, X_train=X_train,
                    kernel_fn=aux[0], fitted=fitted, info=info, op=op)
 
+    @phase("krr.predict")
     def predict(self, X_test: jax.Array, *, mesh=None) -> jax.Array:
         """Out-of-sample prediction K(X_test, landmarks) θ — O(n_test·m·d)
         kernel evaluations, never an n_test × n matrix.  ``mesh`` shards the
@@ -140,21 +142,24 @@ def _fit_from_C(C: jax.Array, W: jax.Array, y: jax.Array, lam: float,
     Returns (theta, fitted, solve-health) — the health dict carries the solve
     ladder's traced scalars and is threaded into ``SketchedKRR.info``."""
     n = y.shape[0]
-    if mesh is not None:
-        from repro.core import distributed as D
+    with phase("krr.gram"):
+        if mesh is not None:
+            from repro.core import distributed as D
 
-        CtC = D.sharded_gram(C, C, mesh)
-        rhs = D.sharded_gram(C, D._pad_to(y[:, None], C.shape[0]), mesh)[:, 0]
-    else:
-        CtC = f32_gram(C, C)
-        rhs = f32_gram(C, y[:, None])[:, 0]           # SᵀK Y  (K symmetric)
+            CtC = D.sharded_gram(C, C, mesh)
+            rhs = D.sharded_gram(C, D._pad_to(y[:, None], C.shape[0]), mesh)[:, 0]
+        else:
+            CtC = f32_gram(C, C)
+            rhs = f32_gram(C, y[:, None])[:, 0]           # SᵀK Y  (K symmetric)
     from repro.resilience.degrade import solve_psd_ladder
 
-    M = CtC + n * lam * W                      # SᵀK²S + nλ SᵀKS
-    theta, health = solve_psd_ladder(M, rhs.astype(M.dtype))
-    return theta, f32_matmul(C, theta)[:n], health
+    with phase("krr.solve"):
+        M = CtC + n * lam * W                      # SᵀK²S + nλ SᵀKS
+        theta, health = solve_psd_ladder(M, rhs.astype(M.dtype))
+        return theta, f32_matmul(C, theta)[:n], health
 
 
+@phase("krr.fit")
 def krr_sketched_fit(
     K: jax.Array, y: jax.Array, lam: float, sk: AccumSketch,
     X_train: jax.Array | None = None, kernel_fn: Callable | None = None,
@@ -174,15 +179,16 @@ def krr_sketched_fit(
     across shards — only d-vectors and d×d blocks cross devices, so the
     Woodbury solve and predict are unchanged."""
     op = A._operator(K)
-    if mesh is not None:
-        from repro.core import distributed as D
+    with phase("krr.sketch"):
+        if mesh is not None:
+            from repro.core import distributed as D
 
-        # C stays padded: its (n, d) slice would sit whole on every device
-        C, W = D.sharded_sketch_both(D._operator_required(K), sk,
-                                     D.resolve_mesh(mesh),
-                                     use_kernel=use_kernel, padded=True)
-    else:
-        C, W = A.sketch_both(K, sk, use_kernel=use_kernel)
+            # C stays padded: its (n, d) slice would sit whole on every device
+            C, W = D.sharded_sketch_both(D._operator_required(K), sk,
+                                         D.resolve_mesh(mesh),
+                                         use_kernel=use_kernel, padded=True)
+        else:
+            C, W = A.sketch_both(K, sk, use_kernel=use_kernel)
     theta, fitted, health = _fit_from_C(C, W, y, lam, mesh=mesh)
     if op is not None:
         return SketchedKRR(theta, sk, None, op.X, op.kernel_fn, fitted,
@@ -213,6 +219,7 @@ def _sketch_left_routed(sk, C, use_kernel: bool | None):
     return A.sketch_left(sk, C)
 
 
+@phase("krr.fit")
 def krr_sketched_fit_matfree(
     X, y: jax.Array, lam: float, sk: AccumSketch,
     kernel_fn: Callable | None = None, *, chunk: int | None = None,
@@ -229,20 +236,21 @@ def krr_sketched_fit_matfree(
     op = A._operator(X)
     if mesh is not None and op is None:
         raise ValueError("mesh= sharding requires a KernelOperator input")
-    if op is not None:
-        if mesh is not None:
-            # fused single launch: W gathered in-body, no second pass over C
-            C, W = op.sketch_both(sk, chunk=chunk, use_kernel=use_kernel,
-                                  mesh=mesh)
+    with phase("krr.sketch"):
+        if op is not None:
+            if mesh is not None:
+                # fused single launch: W gathered in-body, no second pass over C
+                C, W = op.sketch_both(sk, chunk=chunk, use_kernel=use_kernel,
+                                      mesh=mesh)
+            else:
+                C = op.sketch_cols(sk, chunk=chunk, use_kernel=use_kernel)
+                W = _sketch_left_routed(sk, C, use_kernel)
+            X, kernel_fn = op.X, op.kernel_fn
         else:
-            C = op.sketch_cols(sk, chunk=chunk, use_kernel=use_kernel)
+            C = A.sketch_kernel_cols(X, sk, kernel_fn, chunk=chunk)
             W = _sketch_left_routed(sk, C, use_kernel)
-        X, kernel_fn = op.X, op.kernel_fn
-    else:
-        C = A.sketch_kernel_cols(X, sk, kernel_fn, chunk=chunk)
-        W = _sketch_left_routed(sk, C, use_kernel)
-    # symmetrize W: SᵀKS is symmetric in exact arithmetic
-    W = 0.5 * (W + W.T)
+        # symmetrize W: SᵀKS is symmetric in exact arithmetic
+        W = 0.5 * (W + W.T)
     theta, fitted, health = _fit_from_C(C, W, y, lam, mesh=mesh)
     return SketchedKRR(theta, sk, None, X, kernel_fn, fitted, info=health, op=op)
 

@@ -37,6 +37,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.spans import phase
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
@@ -163,6 +165,7 @@ def _normalize_probs(probs: jax.Array | None, n: int,
     return probs / jnp.sum(probs)
 
 
+@phase("krr.draw")
 def make_accum_sketch(
     key: jax.Array,
     n: int,

@@ -123,6 +123,7 @@ def accum_apply(
         scratch_shapes=[pltpu.VMEM((bm, bd), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((R, d), K.dtype),
         interpret=interpret,
+        name="accum_apply",
     )(idx, coef, K)
 
 
@@ -189,6 +190,7 @@ def accum_sketch_both(
             jax.ShapeDtypeStruct((d, d), jnp.float32),
         ),
         interpret=interpret,
+        name="accum_sketch_both",
     )(idx, coef, idx, coef, K)
 
 
@@ -231,6 +233,7 @@ def accum_apply_left(
         out_specs=pl.BlockSpec((d, c), lambda t: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d, c), jnp.float32),
         interpret=interpret,
+        name="accum_apply_left",
     )(idx, coef, M)
 
 
@@ -284,6 +287,7 @@ def accum_step_slab(
         ),
         out_shape=jax.ShapeDtypeStruct((R, d), Cin.dtype),
         interpret=interpret,
+        name="accum_step_slab",
     )(a, idx, coef, K, Cin)
 
 
@@ -370,6 +374,7 @@ def accum_grow_slabs(
             jax.ShapeDtypeStruct((d, d), jnp.float32),
         ),
         interpret=interpret,
+        name="accum_grow_slabs",
     )(a, idx, coef, idx, coef, K, Cin)
 
 
@@ -452,4 +457,5 @@ def matfree_apply(
         out_specs=pl.BlockSpec((bm, d), lambda r, i: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=interpret,
+        name="matfree_apply",
     )(X, L, coef)

@@ -88,6 +88,7 @@ def landmark_attention(
         out_specs=pl.BlockSpec((bq, Dv), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, Dv), q.dtype),
         interpret=interpret,
+        name="landmark_attention",
     )(q, kt, M, bias.astype(jnp.float32)[None, :])
 
 
@@ -186,4 +187,5 @@ def landmark_stats(
             jax.ShapeDtypeStruct((L, Dv), jnp.float32),
         ),
         interpret=interpret,
+        name="landmark_stats",
     )(nv, qt, kt, k, v)

@@ -98,6 +98,7 @@ def _chunked_causal(
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H * Dh)
 
 
+@jax.named_scope("attention")
 def attn_forward(
     p, h: jax.Array, cfg: ModelConfig, sin, cos, *,
     window: int | None = None, q_chunk: int = 512,
@@ -130,6 +131,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> KVCache:
     return KVCache(jnp.zeros(shp, dtype), jnp.zeros(shp, dtype))
 
 
+@jax.named_scope("attention")
 def attn_prefill(
     p, h: jax.Array, cache: KVCache, cfg: ModelConfig, sin, cos, *,
     window: int | None = None, q_chunk: int = 512,
@@ -160,6 +162,7 @@ def attn_prefill(
     return out, cache
 
 
+@jax.named_scope("attention")
 def attn_decode(
     p, h_t: jax.Array, cache: KVCache, pos: jax.Array, cfg: ModelConfig,
     sin_t, cos_t, *, write_pos: jax.Array | None = None,
@@ -211,6 +214,7 @@ def init_attn_sketch_cache(cfg: ModelConfig, batch: int, dtype) -> SketchCache:
     )
 
 
+@jax.named_scope("attention")
 def attn_prefill_sketched(
     p, h: jax.Array, cache: SketchCache, cfg: ModelConfig, sin, cos,
     slot_table: jax.Array, *, chunk: int = 128,
@@ -231,6 +235,7 @@ def attn_prefill_sketched(
     return out, cache
 
 
+@jax.named_scope("attention")
 def attn_decode_sketched(
     p, h_t: jax.Array, cache: SketchCache, cfg: ModelConfig,
     sin_t, cos_t, slots: jax.Array,

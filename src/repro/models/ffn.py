@@ -17,6 +17,7 @@ def init_ffn(key, d_model: int, d_ff: int):
     }
 
 
+@jax.named_scope("mlp")
 def ffn_forward(p, h: jax.Array) -> jax.Array:
     g = jax.nn.silu((h @ p["wi_gate"]).astype(jnp.float32)).astype(h.dtype)
     u = h @ p["wi_up"]

@@ -202,6 +202,7 @@ def loss_fn(
     return loss, {"xent": xent, "aux": aux, "tokens": count}
 
 
+@jax.named_scope("head")
 def _head_logits(h_last: jax.Array, emb: jax.Array) -> jax.Array:
     """(B, D) @ (V, D)ᵀ → (B, V) f32. bf16 operands with f32 accumulation:
     `emb.T.astype(f32)` would materialize a full-vocab f32 weight copy (2.5 GB

@@ -4,7 +4,8 @@ The sketched cache (paper technique) makes per-request memory independent of
 context length — the long_500k production shape decodes against d_slots
 landmark slots instead of a 500k-entry KV cache.
 
-Request lifecycle (each phase is ONE jitted dispatch):
+Request lifecycle (each phase is ONE jitted dispatch; the programs are
+named `jit_prefill_with_cache` and `jit__decode_scan`):
 
   prefill  — `prefill_with_cache`: all L prompt tokens in a single chunked
              forward with a bulk cache write (exact: dynamic_update_slice;
@@ -58,6 +59,7 @@ from repro.models.model import (
 )
 from repro.resilience import faults
 from repro.resilience.degrade import HealthReport
+from repro.spans import span
 
 PyTree = Any
 
@@ -106,6 +108,7 @@ def _slots(cfg: ModelConfig, sc: ServeConfig, slot_key, pos) -> jax.Array:
     )
 
 
+@jax.named_scope("sample")
 def _sample(sc: ServeConfig, sample_key, logits: jax.Array, pos) -> jax.Array:
     if sc.temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -139,6 +142,26 @@ def _decode_scan(
     return jnp.swapaxes(toks, 0, 1), cache
 
 
+def _prefill_program(cfg: ModelConfig, params, cache, tokens, slot_table):
+    return prefill_with_cache(params, tokens, cfg, cache, slot_table=slot_table)
+
+
+def _step_program(cfg: ModelConfig, use_sketch: bool, params, cache, tok, pos, slots):
+    return decode_step(params, cache, tok, pos, cfg, slots=slots, use_sketch=use_sketch)
+
+
+def _program(name: str, fn, *args):
+    """``fn`` with ``args`` bound, under ``name``: the name of its jitted
+    program is ``jit_<name>`` (a bare partial's is ``jit__unknown``), which
+    the profiler's trace shows.  A partial, not a bound method or a closure
+    over the engine: either would hold the engine, and through it the
+    weights, in a reference cycle that outlives `del engine` until the next
+    cyclic collection."""
+    f = functools.partial(fn, *args)
+    f.__name__ = name
+    return f
+
+
 class Engine:
     """Single-host engine; the sharded variant jits with in_shardings from
     repro.sharding (see launch/serve.py)."""
@@ -149,24 +172,17 @@ class Engine:
         self.key = jax.random.PRNGKey(sc.seed)
         self._slot_key = jax.random.fold_in(self.key, _SLOT_STREAM)
         self._sample_key = jax.random.fold_in(self.key, _SAMPLE_STREAM)
+        self._requests = 0      # the `request` id of the engine's spans
         self._step = jax.jit(
-            lambda p, c, t, i, s: decode_step(
-                p, c, t, i, cfg, slots=s, use_sketch=sc.use_sketch
-            )
-        )
+            _program("decode_step", _step_program, cfg, sc.use_sketch))
         self._prefill = jax.jit(
-            lambda p, c, t, st: prefill_with_cache(p, t, cfg, c, slot_table=st)
-        )
+            _program("prefill_with_cache", _prefill_program, cfg))
         # the cache is donated: the scan's carry reuses its buffers, so one
         # copy of the cache is resident, not two (at stablelm-3b width, B=4
-        # and a 2k context the second copy does not fit a 16 GB chip).  It
-        # jits a partial, not a bound method: that would hold the engine, and
-        # through it the weights, in a reference cycle that outlives `del
-        # engine` until the next cyclic collection.
+        # and a 2k context the second copy does not fit a 16 GB chip)
         self._decode = jax.jit(
-            functools.partial(
-                _decode_scan, cfg, sc, self._slot_key, self._sample_key
-            ),
+            _program("_decode_scan", _decode_scan, cfg, sc, self._slot_key,
+                     self._sample_key),
             static_argnames=("n_steps", "use_sketch"),
             donate_argnums=(1,),
         )
@@ -352,7 +368,17 @@ class Engine:
         every `sc.ckpt_every` emitted tokens and an interrupted request
         resumes from <ckpt_dir>/<request_id> with bitwise-identical output
         (every slot draw and sample is a pure function of (seed, position),
-        so cache + emitted tokens IS the complete resume state)."""
+        so cache + emitted tokens IS the complete resume state).
+
+        In the profiler's trace the request is a `repro.engine.generate`
+        span holding one span per phase, all with the same `request` stat
+        (docs/architecture.md, Tracing)."""
+        self._requests += 1
+        rid = self._requests
+        with span("engine.generate", request=rid):
+            return self._generate(prompts, n_new, request_id, rid)
+
+    def _generate(self, prompts, n_new: int, request_id, rid: int):
         B, L = prompts.shape
         use_sketch = self.sc.use_sketch
         ckdir = (
@@ -360,16 +386,23 @@ class Engine:
             if self.sc.ckpt_dir and request_id is not None
             else None
         )
-        resumed = self._try_resume(ckdir, prompts) if ckdir else None
+        resumed = None
+        if ckdir:
+            with span("engine.checkpoint", request=rid):
+                resumed = self._try_resume(ckdir, prompts)
         if resumed is not None:
             cache, toks_done, use_sketch = resumed
         else:
-            cache = self.new_cache(B)
-            cache, logits = self.prefill_tokens(cache, prompts)
-            tok = self._sample(logits, jnp.int32(L))
-            toks_done = np.asarray(tok)[:, None]
+            with span("engine.prefill", request=rid):
+                cache = self.new_cache(B)
+                cache, logits = self.prefill_tokens(cache, prompts)
+            # token 0 on the host: the time to first token ends here
+            with span("engine.first_token", request=rid):
+                tok = self._sample(logits, jnp.int32(L))
+                toks_done = np.asarray(tok)[:, None]
             if ckdir:
-                self._save_request(ckdir, cache, toks_done, use_sketch, prompts)
+                with span("engine.checkpoint", request=rid):
+                    self._save_request(ckdir, cache, toks_done, use_sketch, prompts)
         while toks_done.shape[1] < n_new:
             emitted = toks_done.shape[1]
             remaining = n_new - emitted
@@ -381,7 +414,8 @@ class Engine:
             # "nan"/"inf"/"zero" poison the cache the health screen must catch)
             cache = faults.poison("decode.step", cache)
             if self.sc.health_check:
-                reason = self._cache_bad(cache, use_sketch)
+                with span("engine.health_check", request=rid):
+                    reason = self._cache_bad(cache, use_sketch)
                 if reason:
                     self.health.record(
                         "decode.cache",
@@ -391,13 +425,15 @@ class Engine:
                     )
                     cache = self._rebuild_exact(prompts, toks_done)
                     use_sketch = False
-            toks, cache = self._decode(
-                self.params, cache, jnp.asarray(toks_done[:, -1]),
-                jnp.int32(L + emitted - 1), n_steps=chunk, use_sketch=use_sketch,
-            )
-            toks_done = np.concatenate([toks_done, np.asarray(toks)], axis=1)
+            with span("engine.decode", request=rid, steps=chunk):
+                toks, cache = self._decode(
+                    self.params, cache, jnp.asarray(toks_done[:, -1]),
+                    jnp.int32(L + emitted - 1), n_steps=chunk, use_sketch=use_sketch,
+                )
+                toks_done = np.concatenate([toks_done, np.asarray(toks)], axis=1)
             if ckdir:
-                self._save_request(ckdir, cache, toks_done, use_sketch, prompts)
+                with span("engine.checkpoint", request=rid):
+                    self._save_request(ckdir, cache, toks_done, use_sketch, prompts)
         return toks_done[:, :n_new], cache
 
     def _sample(self, logits: jax.Array, pos) -> jax.Array:
