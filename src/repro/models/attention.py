@@ -16,6 +16,7 @@ from repro.core.sketched_attention import (
     update_sketch_cache,
 )
 from repro.models.common import apply_rope, dense_init
+from repro.sharding import model_split
 
 NEG_INF = -1e30
 
@@ -120,7 +121,17 @@ class KVCache(NamedTuple):
     Hkv·Dh).  A (…, Hkv, Dh) layout pads Dh to the TPU's 128 lanes (1.6× at
     stablelm-3b's Dh = 80) and made XLA re-tile the whole cache inside the
     decode loop; at stablelm-3b width, batch 4 and a 2k context that no
-    longer fit a 16 GB chip."""
+    longer fit a 16 GB chip.
+
+    In a `DecodeCache` each leaf is stacked over the layers, (n_layers, B,
+    S_max, Hkv·Dh), and decode keeps that stack as one resident buffer: the
+    layer scan of `decode_step` carries it, each layer reads its K and V
+    from it in place, in the stored dtype and layout (`attn_decode`), and
+    writes back only the new token's row.  Passing the stack to the scan as
+    `xs`/`ys` instead slices each layer out and builds a new stack, and an
+    `.astype(float32)` of a layer's cache is one more full copy (in a
+    transposed layout at Dh = 80): each is a whole-cache pass through HBM
+    per step, ~27 of the 44 ms of a stablelm-3b decode step on a TPU v5e."""
 
     k: jax.Array  # (B, S_max, Hkv·Dh)
     v: jax.Array  # (B, S_max, Hkv·Dh)
@@ -162,43 +173,116 @@ def attn_prefill(
     return out, cache
 
 
+def _exact_parts(x: jax.Array, dtype) -> tuple[tuple[jax.Array, ...], jax.lax.Precision]:
+    """``x`` as the operands of a contraction with cache values of ``dtype``
+    such that every product is exact in float32: ``x`` at HIGHEST against a
+    float32 cache; against a bfloat16 one, bfloat16 ``x`` as it is and any
+    other ``x`` as three bfloat16 parts that sum to it exactly (8 + 8 + 8 of
+    float32's 24 significant bits, over the same exponent range)."""
+    if dtype == jnp.float32:
+        return (x.astype(jnp.float32),), jax.lax.Precision.HIGHEST
+    if dtype != jnp.bfloat16:
+        raise ValueError(f"exact decode reads a float32 or bfloat16 cache, not {dtype}")
+    if x.dtype == jnp.bfloat16:
+        return (x,), jax.lax.Precision.DEFAULT
+    # reduce_precision and not a float32 → bfloat16 → float32 round trip,
+    # which a compiler allowed excess precision may fold to the identity and
+    # so zero the remainders
+    r, parts = x.astype(jnp.float32), []
+    for _ in range(3):
+        hi = jax.lax.reduce_precision(r, exponent_bits=8, mantissa_bits=7)
+        parts.append(hi.astype(jnp.bfloat16))
+        r = r - hi
+    return tuple(parts), jax.lax.Precision.DEFAULT
+
+
+def _decode_attend(q: jax.Array, ck: jax.Array, cv: jax.Array, pos, *,
+                   per_head: bool = False) -> jax.Array:
+    """Softmax attention of one query per sequence over an exact cache as it
+    is stored: q (B, H, Dh), ck/cv (B, S, Hkv·Dh), slot s valid iff s <= pos
+    → (B, H, Dh) float32.  Logits and softmax are float32, and every product
+    of a cache value is exact in float32 (`_exact_parts`), as in an upcast:
+    only the summation order differs.
+
+    By default neither contraction upcasts or re-lays-out the cache: the
+    query enters as a block-diagonal (Hkv·Dh, H) matrix, so the scores are
+    one (S, Hkv·Dh) @ (Hkv·Dh, H) product per sequence, and the weighted sum
+    is (H, S) @ (S, Hkv·Dh), of which each head keeps its own Dh-wide block.
+    That is Hkv times the useful multiplies (by count ~0.9 ms a stablelm-3b
+    step at the bf16 peak, the probabilities' three parts included), done
+    while the cache streams from HBM; a (B, S, Hkv, Dh) view of the cache
+    instead pads Dh = 80 to 128 lanes and XLA copies the whole cache to make
+    it.  ``per_head`` takes that view all the same, for a cache split over
+    devices along S or Hkv·Dh: there the block-diagonal products contract
+    over the split axis, so (B, S, H) scores or (B, 3H, Hkv·Dh) partial
+    outputs would be summed across devices; per KV head, each device keeps
+    its own sums."""
+    B, H, Dh = q.shape
+    S, HD = ck.shape[1:]
+    Hkv = HD // Dh
+    G = H // Hkv
+    scale = 1.0 / jnp.sqrt(jnp.asarray(Dh, jnp.float32))
+    if per_head:
+        ck, cv = ck.reshape(B, S, Hkv, Dh), cv.reshape(B, S, Hkv, Dh)
+        parts, prec = _exact_parts(q.reshape(B, Hkv, G, Dh), ck.dtype)
+        s = jnp.einsum("bhgd,bshd->bshg", jnp.concatenate(parts, axis=2), ck,
+                       precision=prec, preferred_element_type=jnp.float32)
+        s = s.reshape(B, S, Hkv, len(parts), G).sum(axis=3).reshape(B, S, H)
+    else:
+        own = jnp.arange(Hkv)[:, None] == jnp.arange(H)[None, :] // G
+        qbd = jnp.where(own[None, :, None, :], q.transpose(0, 2, 1)[:, None], 0)
+        parts, prec = _exact_parts(qbd.reshape(B, HD, H), ck.dtype)
+        s = jnp.einsum("bsk,bkn->bsn", ck, jnp.concatenate(parts, axis=-1),
+                       precision=prec, preferred_element_type=jnp.float32)
+        s = s.reshape(B, S, len(parts), H).sum(axis=2)
+    logits = jnp.where((jnp.arange(S) <= pos)[None, :, None], s * scale, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=1)
+    if per_head:
+        parts, prec = _exact_parts(probs.reshape(B, S, Hkv, G), cv.dtype)
+        o = jnp.einsum("bshg,bshd->bhgd", jnp.concatenate(parts, axis=3), cv,
+                       precision=prec, preferred_element_type=jnp.float32)
+        return o.reshape(B, Hkv, len(parts), G, Dh).sum(axis=2).reshape(B, H, Dh)
+    parts, prec = _exact_parts(probs, cv.dtype)
+    o = jnp.einsum("bsn,bsk->bnk", jnp.concatenate(parts, axis=-1), cv,
+                   precision=prec, preferred_element_type=jnp.float32)
+    o = o.reshape(B, len(parts), H, Hkv, Dh)
+    return jnp.where(own.T[None, None, :, :, None], o, 0.0).sum(axis=(1, 3))
+
+
 @jax.named_scope("attention")
 def attn_decode(
-    p, h_t: jax.Array, cache: KVCache, pos: jax.Array, cfg: ModelConfig,
-    sin_t, cos_t, *, write_pos: jax.Array | None = None,
+    p, h_t: jax.Array, cache: KVCache, layer: jax.Array, pos: jax.Array,
+    cfg: ModelConfig, sin_t, cos_t, *, write_pos: jax.Array | None = None,
 ) -> tuple[jax.Array, KVCache]:
-    """One-token decode. h_t: (B, 1, D); pos: scalar current absolute index.
+    """One-token decode against layer ``layer`` of the stacked exact cache
+    (leaves (n_layers, B, S_cache, Hkv·Dh), see `KVCache`). h_t: (B, 1, D);
+    pos: scalar current absolute index.  Writes the token's K/V row in place
+    at (layer, :, write_pos, :) and reads the layer's K/V where they lie,
+    per KV head where the current mesh splits the cache over devices
+    (`_decode_attend`).  Returns (out (B, 1, D), the stack).
 
     `write_pos` defaults to pos; a ring-buffer (sliding-window) cache passes
     pos % window. Validity mask: slot s is valid iff s <= pos (for a full
     cache) — for a ring buffer once pos >= S_cache-1 every slot is valid,
     which the same comparison yields since pos keeps growing."""
     B = h_t.shape[0]
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    G = H // Hkv
+    H, Dh = cfg.n_heads, cfg.head_dim
     if write_pos is None:
         write_pos = pos
     q, k, v = _qkv(p, h_t, cfg, sin_t, cos_t)                       # (B,1,·,Dh)
+    # the barrier keeps XLA from fusing the V projection into the row write:
+    # fused, it laid the whole V stack out for the write, (S, B, Hkv·Dh), and
+    # then copied each layer's V out of it to read it, and the stack back
+    k, v = jax.lax.optimization_barrier(
+        (k.astype(cache.k.dtype), v.astype(cache.v.dtype)))
+    at = (layer, 0, write_pos, 0)
     cache = KVCache(
-        jax.lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype).reshape(B, 1, -1),
-                                     (0, write_pos, 0)),
-        jax.lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype).reshape(B, 1, -1),
-                                     (0, write_pos, 0)),
+        jax.lax.dynamic_update_slice(cache.k, k.reshape(1, B, 1, -1), at),
+        jax.lax.dynamic_update_slice(cache.v, v.reshape(1, B, 1, -1), at),
     )
-    S = cache.k.shape[1]
-    ck = cache.k.reshape(B, S, Hkv, Dh)
-    cv = cache.v.reshape(B, S, Hkv, Dh)
-    kpos = jnp.arange(S)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(Dh, jnp.float32))
-    qg = q.reshape(B, Hkv, G, Dh)
-    logits = jnp.einsum(
-        "bhgd,bshd->bhgs", qg.astype(jnp.float32), ck.astype(jnp.float32)
-    ) * scale
-    mask = kpos <= pos
-    logits = jnp.where(mask[None, None, None, :], logits, NEG_INF)
-    o = jnp.einsum(
-        "bhgs,bshd->bhgd", jax.nn.softmax(logits, axis=-1), cv.astype(jnp.float32)
-    )
+    o = _decode_attend(q[:, 0], jax.lax.dynamic_index_in_dim(cache.k, layer, 0, False),
+                       jax.lax.dynamic_index_in_dim(cache.v, layer, 0, False), pos,
+                       per_head=model_split(cfg.sharding_policy))
     out = o.reshape(B, 1, H * Dh).astype(h_t.dtype) @ p["wo"]
     return out, cache
 

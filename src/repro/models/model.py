@@ -263,21 +263,23 @@ def init_cache(
     return DecodeCache(blocks)
 
 
-def _block_decode(kind, bp, shared, h, state, cfg, sin_t, cos_t, pos, slots, use_sketch):
+def _block_decode(kind, bp, shared, h, state, layer, cfg, sin_t, cos_t, pos, slots,
+                  use_sketch):
+    """One block's decode step.  An exact `KVCache` state is the whole
+    stack over superblocks, indexed by ``layer``; any other state is this
+    layer's own."""
     eps = cfg.norm_eps
     if kind in ("attn", "attn_local", "attn_shared"):
         p = shared if kind == "attn_shared" else bp
         x = rmsnorm(h, p["attn"]["norm"], eps)
         if isinstance(state, SketchCache):
             y, state = att.attn_decode_sketched(p["attn"], x, state, cfg, sin_t, cos_t, slots)
-        elif kind == "attn_local":
-            # ring-buffer sliding-window cache: write at pos % window
-            y, state = att.attn_decode(
-                p["attn"], x, state, pos, cfg, sin_t, cos_t,
-                write_pos=pos % state.k.shape[1],
-            )
         else:
-            y, state = att.attn_decode(p["attn"], x, state, pos, cfg, sin_t, cos_t)
+            # a ring-buffer sliding-window cache writes at pos % window
+            y, state = att.attn_decode(
+                p["attn"], x, state, layer, pos, cfg, sin_t, cos_t,
+                write_pos=pos % state.k.shape[2] if kind == "attn_local" else None,
+            )
         h = h + y
         if "ffn" in p:
             x = rmsnorm(h, p["ffn"]["norm"], eps)
@@ -389,26 +391,36 @@ def decode_step(
 ) -> tuple[jax.Array, DecodeCache]:
     """One decoding step. token_t: (B,) int32; pos: scalar int32 (current index).
 
-    Returns (logits (B, V), updated cache). The scan mirrors forward()."""
+    Returns (logits (B, V), updated cache). The scan mirrors forward().  It
+    carries the exact `KVCache` stacks, which each layer indexes, reads in
+    place and writes one row of (see `KVCache`); the small sketched and
+    recurrent states go through it per layer, as `xs` and `ys`."""
     h = jnp.take(params["embed"], token_t[:, None], axis=0)
     h = h * jnp.sqrt(jnp.asarray(cfg.d_model, h.dtype))
     h = constrain(h, "dp", None, None, policy=cfg.sharding_policy)
     sin_t, cos_t = rope_table(pos[None], cfg.head_dim, cfg.rope_theta)
     shared = params["shared"]
+    kv = {n: c for n, c in cache.blocks.items() if isinstance(c, att.KVCache)}
+    rest = {n: c for n, c in cache.blocks.items() if n not in kv}
 
-    def superblock(h, xs):
-        sb_params, sb_cache = xs
-        new_states = {}
+    def superblock(carry, xs):
+        h, kv = carry
+        sb_params, sb_rest, layer = xs
+        kv, new_rest = dict(kv), {}
         for i, kind in enumerate(cfg.pattern):
-            bp = sb_params.get(f"pos{i}")
-            h, st = _block_decode(
-                kind, bp, shared, h, sb_cache[f"pos{i}"], cfg, sin_t, cos_t,
-                pos, slots, use_sketch,
+            name = f"pos{i}"
+            states = kv if name in kv else new_rest
+            state = kv[name] if name in kv else sb_rest[name]
+            h, states[name] = _block_decode(
+                kind, sb_params.get(name), shared, h, state, layer, cfg,
+                sin_t, cos_t, pos, slots, use_sketch,
             )
-            new_states[f"pos{i}"] = st
-        return h, new_states
+        return (h, kv), new_rest
 
-    h, new_blocks = jax.lax.scan(superblock, h, (params["blocks"], cache.blocks))
+    (h, kv), rest = jax.lax.scan(
+        superblock, (h, kv),
+        (params["blocks"], rest, jnp.arange(cfg.n_superblocks)),
+    )
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = _head_logits(h[:, 0], output_embedding(params))
-    return logits, DecodeCache(new_blocks)
+    return logits, DecodeCache({**rest, **kv})

@@ -183,6 +183,13 @@ def _current_mesh():
     return None if m.empty else m
 
 
+def model_split(policy: str = "default") -> bool:
+    """Whether the current mesh splits arrays over a "model" axis of more than
+    one device under `policy`, as `cache_shardings` splits decode caches."""
+    m = _current_mesh()
+    return m is not None and policy != "dp_only" and m.shape.get("model", 1) > 1
+
+
 def constrain(x: jax.Array, *entries, policy: str = "default") -> jax.Array:
     """with_sharding_constraint that (a) is a no-op outside a mesh context and
     (b) drops axes absent from the mesh / non-divisible dims. Entries use the

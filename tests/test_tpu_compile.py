@@ -6,19 +6,26 @@ tiling, unsupported ops.  These tests lower each kernel through its public
 entry point with ``interpret=False`` at deployment widths for one chip of a
 described ``v5e:2x2`` topology and compile it — no chip is needed, nothing
 runs.  Under ``jit`` the entry points take their heuristic tilings, which are
-the tilings every traced caller runs with.
+the tilings every traced caller runs with.  The same described topology, as a
+2 × 2 mesh, shows which collectives a sharded decode step compiles to.
 
 The topology is described inside a module fixture, never at import: only one
 process may hold the TPU library, and a test worker that loads it keeps it
 until it exits.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.configs.base import ShapeSpec
 from repro.core.sketch import AccumSketch
 from repro.kernels.accum_apply.ops import (
     accum_grow_kernel,
@@ -29,10 +36,11 @@ from repro.kernels.accum_apply.ops import (
     sketch_step_kernel,
 )
 from repro.kernels.landmark_attention.ops import landmark_attend, landmark_stats_fused
+from repro.launch.specs import decode_cell
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -44,8 +52,18 @@ def one_chip():
     # be read back without one — keep the cache out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
 
 
 def _compile(fn, one_chip, *shapes):
@@ -135,3 +153,26 @@ def test_landmark_stats_compiles_stablelm_heads(one_chip):
     Dh, L, S = 80, 1024, 2048
     _compile(lambda qt, kt, k, v: landmark_stats_fused(qt, kt, k, v, interpret=False),
              one_chip, ((L, Dh), F32), ((L, Dh), F32), ((S, Dh), F32), ((S, Dh), F32))
+
+
+@pytest.mark.parametrize("S", [2112, 8192], ids=["heads_split", "positions_split"])
+def test_sharded_decode_step_sums_no_scores_across_chips(mesh_2x2, S):
+    """One stablelm-3b layer at batch 8 on a (data 2 × model 2) mesh.
+    `cache_shardings` splits the cache's largest non-batch axis over "model":
+    Hkv·Dh = 2560 at 2112 positions, the positions at 8192.  Decode reads the
+    cache per KV head there, so no all-reduce carries (B, S, H) scores or
+    block-diagonal (…, H, Hkv·Dh) partial outputs; what is left are the
+    output projection's, the softmax's and, where the positions are split,
+    the weighted sum's (B, H, Dh) parts."""
+    cfg = dataclasses.replace(get_config("stablelm-3b"), n_superblocks=1, n_layers=1)
+    cell = decode_cell(cfg, ShapeSpec("decode", S, 8, "decode"), mesh_2x2)
+    with mesh_2x2:
+        text = jax.jit(cell.fn, in_shardings=cell.in_shardings).lower(
+            *cell.args).compile().as_text()
+    reduced = [[int(d) for d in m.split(",") if d] for m in
+               re.findall(r"= [a-z0-9]+\[([0-9,]*)\]\S* all-reduce(?:-start)?\(", text)]
+    assert reduced
+    H, HD = cfg.n_heads, cfg.n_kv_heads * cfg.head_dim
+    for dims in reduced:
+        assert S not in dims and S // 2 not in dims, dims
+        assert math.prod(dims) < H * HD, dims
